@@ -97,7 +97,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.ring import (AXIS, ParityAccum, RingMachine, StepAccum,
                              block_row, gbuf_add, ring_add, zeros_block)
 from repro.models import lora as lora_mod
@@ -211,9 +210,8 @@ def roundpipe_forward_backward(params, batch, worker_id, grad_residual=None,
                if tick_program is not None
                else plan.tick_program(r_total, g0=g0))
     g0 = program.g0                        # the IR's rotation stamp governs
-    # worker id from a P(AXIS)-sharded iota input rather than axis_index —
-    # the latter lowers to PartitionId, unsupported under partial-auto SPMD
-    # on older JAX (see repro.compat).  ``w`` is the LOGICAL ring position:
+    # worker id from a P(AXIS)-sharded iota input (one entry per worker).
+    # ``w`` is the LOGICAL ring position:
     # physical worker p sits at logical (p - g0) mod N (g0=0: identity).
     w = worker_id[0] if g0 == 0 else (worker_id[0] - g0) % n
 
@@ -1173,15 +1171,15 @@ def _build_mapped(cfg: ModelConfig, mesh, plan, *, xent_chunk: int,
                 lambda leaf: P(None, AXIS, *([None] * (leaf.ndim - 2))),
                 batch)
         if grad_compress != "none":
-            f = shard_map(
-                body, mesh, axis_names={AXIS},
+            f = jax.shard_map(
+                body, mesh=mesh, axis_names={AXIS},
                 in_specs=(pspecs, bspecs, P(AXIS), res_specs),
                 out_specs=(grads_specs, P(), P(), res_specs),
                 check_vma=False)
             return f(padded_params, batch, jnp.arange(n, dtype=jnp.int32),
                      grad_residual)
-        f = shard_map(
-            body, mesh, axis_names={AXIS},
+        f = jax.shard_map(
+            body, mesh=mesh, axis_names={AXIS},
             in_specs=(pspecs, bspecs, P(AXIS)),
             out_specs=(grads_specs, P(), P()),
             check_vma=False)
@@ -1590,8 +1588,8 @@ def build_roundpipe_async_train_step(cfg: ModelConfig, mesh, step_cfg,
         batches = jax.tree.map(
             lambda x: x.reshape(x.shape[0], rounds, x.shape[1] // rounds,
                                 *x.shape[2:]), batches)
-        f = shard_map(
-            body, mesh, axis_names={AXIS},
+        f = jax.shard_map(
+            body, mesh=mesh, axis_names={AXIS},
             in_specs=(pspecs, ospecs, inner_bspecs, P(AXIS)),
             out_specs=(pspecs, ospecs, P()),
             check_vma=False)

@@ -344,9 +344,10 @@ class RingMachine:
         folds a re-quantization of each fresh version into its ``D_T``
         update tick."""
         leaves = jax.tree_util.tree_flatten(pool)[0]
+        # concatenate in the pool's own dtype: quantize_rows widens per
+        # block, so no fp32 copy of the whole pool is materialized
         pool_cat = jnp.concatenate(
-            [l.reshape(self.per, -1).astype(jnp.float32) for l in leaves],
-            axis=1)                                 # (per, row_elems)
+            [l.reshape(self.per, -1) for l in leaves], axis=1)
         return quantize_rows(pool_cat, bits=POOL_DTYPE_BITS[self.pool_dtype])
 
     def zeros_standby_q(self, qpair):
@@ -382,11 +383,12 @@ class RingMachine:
         return codes, scales
 
     def dequant_block(self, codes, scales, spec):
-        """Fused dequant-on-upload: codes+scales -> injection block in
-        compute precision (``kernels.ops.dequant_rows``), split back into
+        """Fused dequant-on-upload: codes+scales -> injection block in the
+        pool's compute dtype (``kernels.ops.dequant_rows``), split back into
         the pool's leaf structure with the same real-weight padding rows as
         ``assemble_block``."""
-        flat = kops.dequant_rows(codes, scales)     # (kmax, nb*QB) fp32
+        flat = kops.dequant_rows(                   # (kmax, nb*QB)
+            codes, scales, out_dtype=jnp.result_type(*self.leaf_dtypes))
         flat = flat[:, :self.row_elems]
         if spec.size < self.kmax:
             pad = jnp.broadcast_to(

@@ -48,18 +48,21 @@ def quantize_rows(rows, *, bits: int = 8, block: int = QUANT_BLOCK):
                      -qmax, qmax).astype(jnp.int8)
     codes = codes.reshape(r, nb * block)
     if bits == 4:
-        codes = pack_int4(codes)
+        codes = pack_int4(codes, block)
     return codes, scale
 
 
-def pack_int4(codes):
-    """int8 codes in [-8, 7], even last dim -> uint8 nibble pairs.
+def pack_int4(codes, block: int = QUANT_BLOCK):
+    """int8 codes in [-8, 7] -> uint8 nibble pairs, half the bytes.
 
-    Element 2i lands in the low nibble of byte i, element 2i+1 in the high
-    nibble — the order :func:`unpack_int4` (and the kernel) restores."""
-    u = (codes.astype(jnp.int32) & 0xF).astype(jnp.uint8)
-    lo, hi = u[..., 0::2], u[..., 1::2]
-    return lo | (hi << 4)
+    Within each run of ``block`` codes, code ``i`` lands in the low nibble of
+    byte ``i`` and code ``i + block // 2`` in the high nibble, so the kernel
+    widens a byte tile into two lane-aligned half-blocks (no interleave).
+    The last dim must be a multiple of ``block``."""
+    *lead, n = codes.shape
+    half = (codes.astype(jnp.int32) & 0xF).astype(jnp.uint8).reshape(
+        *lead, n // block, 2, block // 2)
+    return (half[..., 0, :] | (half[..., 1, :] << 4)).reshape(*lead, n // 2)
 
 
 def _widen_nibble(n):
@@ -68,39 +71,52 @@ def _widen_nibble(n):
     return n - 16 * (n >> 3)
 
 
-def unpack_int4(packed):
+def unpack_int4(packed, block: int = QUANT_BLOCK):
     """uint8 nibble pairs -> int8 codes, inverse of :func:`pack_int4`."""
-    p = packed.astype(jnp.int32)
+    *lead, n = packed.shape
+    p = packed.astype(jnp.int32).reshape(*lead, 2 * n // block, block // 2)
     lo, hi = _widen_nibble(p & 0xF), _widen_nibble((p >> 4) & 0xF)
-    pair = jnp.stack([lo, hi], axis=-1)
-    return pair.reshape(*packed.shape[:-1], packed.shape[-1] * 2).astype(jnp.int8)
+    return jnp.concatenate([lo, hi], axis=-1).reshape(
+        *lead, 2 * n).astype(jnp.int8)
 
 
 # ---------------------------------------------------------------------------
 # Pallas kernels: codes + scales -> compute-precision rows
 # ---------------------------------------------------------------------------
 
-def _dequant8_kernel(codes_ref, scale_ref, out_ref, *, out_dtype):
-    # codes (1, block), scale (1, 1): widen, rescale, cast — one fused pass
-    x = codes_ref[...].astype(jnp.float32) * scale_ref[...].astype(jnp.float32)
-    out_ref[...] = x.astype(out_dtype)
+ROW_TILE = 8         # pool rows per program (f32 sublane tile)
+SCALE_TILE = 128     # scale blocks per program: one lane-width of scales
 
 
-def _dequant4_kernel(packed_ref, scale_ref, out_ref, *, out_dtype):
-    p = packed_ref[...].astype(jnp.int32)                  # (1, block // 2)
-    lo, hi = _widen_nibble(p & 0xF), _widen_nibble((p >> 4) & 0xF)
-    pair = jnp.stack([lo, hi], axis=-1)                    # (1, block//2, 2)
-    vals = pair.reshape(p.shape[0], p.shape[1] * 2).astype(jnp.float32)
-    out_ref[...] = (vals * scale_ref[...].astype(jnp.float32)).astype(out_dtype)
+def _dequant_kernel(codes_ref, scale_ref, out_ref, *, block, packed,
+                    n_blocks):
+    # codes (rows, n_blocks * code_cols), scales (rows, n_blocks): widen,
+    # rescale and cast one scale block at a time, all in one pass
+    s = scale_ref[...].astype(jnp.float32)
+    half = block // 2
+    for j in range(n_blocks):
+        sj = s[:, j:j + 1]
+        if packed:
+            p = codes_ref[:, j * half:(j + 1) * half].astype(jnp.int32)
+            for k, nib in enumerate((p & 0xF, (p >> 4) & 0xF)):
+                lo = j * block + k * half
+                out_ref[:, lo:lo + half] = (
+                    _widen_nibble(nib).astype(jnp.float32) * sj
+                ).astype(out_ref.dtype)
+        else:
+            c = codes_ref[:, j * block:(j + 1) * block].astype(jnp.float32)
+            out_ref[:, j * block:(j + 1) * block] = (c * sj).astype(
+                out_ref.dtype)
 
 
 def dequant_rows(codes, scales, *, block: int = QUANT_BLOCK,
                  out_dtype=jnp.float32, interpret: bool = False):
     """(codes, scales) from :func:`quantize_rows` -> (R, nb*block) rows.
 
-    codes int8 selects the 8-bit kernel; uint8 the packed-int4 kernel (the
-    storage dtype IS the format tag).  Grid is (rows, blocks): each program
-    dequantizes one scale-block of one row.
+    codes int8 selects 8-bit codes; uint8 the packed int4 pairs (the storage
+    dtype IS the format tag).  Each program dequantizes a tile of
+    ``ROW_TILE`` rows by ``SCALE_TILE`` scale blocks, so every block's last
+    two dims meet the TPU's (8, 128) tiling; edge tiles are partial.
     """
     r, nb = scales.shape
     packed = codes.dtype == jnp.uint8
@@ -108,16 +124,18 @@ def dequant_rows(codes, scales, *, block: int = QUANT_BLOCK,
     if codes.shape != (r, nb * code_cols):
         raise ValueError(f"codes {codes.shape} do not match scales {scales.shape} "
                          f"with block={block}")
-    kernel = functools.partial(
-        _dequant4_kernel if packed else _dequant8_kernel, out_dtype=out_dtype)
+    tr = ROW_TILE if r > ROW_TILE else r
+    tb = SCALE_TILE if nb > SCALE_TILE else nb
+    kernel = functools.partial(_dequant_kernel, block=block, packed=packed,
+                               n_blocks=tb)
     return pl.pallas_call(
         kernel,
-        grid=(r, nb),
+        grid=(pl.cdiv(r, tr), pl.cdiv(nb, tb)),
         in_specs=[
-            pl.BlockSpec((1, code_cols), lambda ri, bi: (ri, bi)),
-            pl.BlockSpec((1, 1), lambda ri, bi: (ri, bi)),
+            pl.BlockSpec((tr, tb * code_cols), lambda ri, bi: (ri, bi)),
+            pl.BlockSpec((tr, tb), lambda ri, bi: (ri, bi)),
         ],
-        out_specs=pl.BlockSpec((1, block), lambda ri, bi: (ri, bi)),
+        out_specs=pl.BlockSpec((tr, tb * block), lambda ri, bi: (ri, bi)),
         out_shape=jax.ShapeDtypeStruct((r, nb * block), out_dtype),
         interpret=interpret,
     )(codes, scales)

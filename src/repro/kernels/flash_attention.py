@@ -46,9 +46,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     @pl.when(live if not isinstance(live, bool) else True)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)          # (bq, d)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)          # (bk, d)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)          # (bk, dv)
+        q = q_ref[...].astype(jnp.float32)                 # (bq, d)
+        k = k_ref[...].astype(jnp.float32)                 # (bk, d)
+        v = v_ref[...].astype(jnp.float32)                 # (bk, dv)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
@@ -71,8 +71,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     @pl.when(ki == n_kv_blocks - 1)
     def _final():
-        o_ref[0, :, 0, :] = (acc_scr[...]
-                             / jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...]
+                      / jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
 
 
 def flash_attention(q, k, v, *, causal=True, sliding_window=None,
@@ -81,7 +81,9 @@ def flash_attention(q, k, v, *, causal=True, sliding_window=None,
     """q: (B,Sq,H,Dh); k,v: (B,Skv,KH,Dh|Dv) -> (B,Sq,H,Dv).
 
     GQA is handled by mapping query head h to kv head h // (H // KH) in the
-    BlockSpec index maps (no materialised KV broadcast).
+    BlockSpec index maps (no materialised KV broadcast).  The kernel runs
+    head-major, (B, H, S, D), so each block's last two dims are a
+    (sequence, head_dim) tile as the TPU's tiling requires.
     """
     b, sq, h, dh = q.shape
     skv, kh = k.shape[1], k.shape[2]
@@ -100,17 +102,21 @@ def flash_attention(q, k, v, *, causal=True, sliding_window=None,
         n_kv_blocks=nk, causal=causal, sliding_window=sliding_window,
         seq_kv=skv)
 
-    return pl.pallas_call(
+    q, k, v = (jnp.swapaxes(a, 1, 2) for a in (q, k, v))
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, dh), lambda bi, hi, qi, ki: (bi, qi, hi, 0)),
-            pl.BlockSpec((1, block_k, 1, dh), lambda bi, hi, qi, ki: (bi, ki, hi // g, 0)),
-            pl.BlockSpec((1, block_k, 1, dv), lambda bi, hi, qi, ki: (bi, ki, hi // g, 0)),
+            pl.BlockSpec((None, None, block_q, dh),
+                         lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+            pl.BlockSpec((None, None, block_k, dh),
+                         lambda bi, hi, qi, ki: (bi, hi // g, ki, 0)),
+            pl.BlockSpec((None, None, block_k, dv),
+                         lambda bi, hi, qi, ki: (bi, hi // g, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, dv),
-                               lambda bi, hi, qi, ki: (bi, qi, hi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, sq, h, dv), q.dtype),
+        out_specs=pl.BlockSpec((None, None, block_q, dv),
+                               lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, h, sq, dv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),    # running max
             pltpu.VMEM((block_q, 1), jnp.float32),    # running sum
@@ -118,3 +124,4 @@ def flash_attention(q, k, v, *, causal=True, sliding_window=None,
         ],
         interpret=interpret,
     )(q, k, v)
+    return jnp.swapaxes(out, 1, 2)

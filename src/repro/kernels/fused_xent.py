@@ -20,7 +20,7 @@ NEG_INF = -1e30
 
 
 def _xent_kernel(labels_ref, x_ref, w_ref, loss_ref, m_scr, l_scr, gold_scr,
-                 *, block_t, block_v, n_v_blocks):
+                 *, block_v, n_v_blocks, vocab):
     vi = pl.program_id(1)
 
     @pl.when(vi == 0)
@@ -29,15 +29,13 @@ def _xent_kernel(labels_ref, x_ref, w_ref, loss_ref, m_scr, l_scr, gold_scr,
         l_scr[...] = jnp.zeros_like(l_scr)
         gold_scr[...] = jnp.zeros_like(gold_scr)
 
-    x = x_ref[...].astype(jnp.float32)                     # (bt, d)
-    w = w_ref[...].astype(jnp.float32)                     # (d, bv)
-    logits = jax.lax.dot_general(x, w, (((1,), (0,)), ((), ())),
+    logits = jax.lax.dot_general(x_ref[...], w_ref[...],  # (bt, d) @ (d, bv)
+                                 (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-    labels = labels_ref[...]                               # (bt,)
-    v0 = vi * block_v
-    col = v0 + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
-    is_gold = col == labels[:, None]
-    gold_scr[...] += jnp.sum(jnp.where(is_gold, logits, 0.0), axis=1,
+    labels = labels_ref[...]                               # (bt, 1)
+    col = vi * block_v + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+    logits = jnp.where(col < vocab, logits, NEG_INF)       # vocab padding
+    gold_scr[...] += jnp.sum(jnp.where(col == labels, logits, 0.0), axis=1,
                              keepdims=True)
 
     m_prev = m_scr[...]
@@ -49,7 +47,14 @@ def _xent_kernel(labels_ref, x_ref, w_ref, loss_ref, m_scr, l_scr, gold_scr,
     @pl.when(vi == n_v_blocks - 1)
     def _final():
         lse = m_scr[...] + jnp.log(jnp.maximum(l_scr[...], 1e-30))
-        loss_ref[...] = (lse - gold_scr[...])[:, 0]
+        loss_ref[...] = lse - gold_scr[...]
+
+
+def _pad_vocab(w, block_v):
+    """Zero-pad the vocab dim of ``w`` up to a multiple of ``block_v``; the
+    padded columns are masked to -inf wherever logits are formed."""
+    v = w.shape[1]
+    return jnp.pad(w, ((0, 0), (0, -v % block_v)))
 
 
 def _xent_forward(x, w, labels, *, block_t, block_v, interpret):
@@ -57,33 +62,37 @@ def _xent_forward(x, w, labels, *, block_t, block_v, interpret):
     v = w.shape[1]
     block_t = min(block_t, t)
     block_v = min(block_v, v)
-    nt, nv = -(-t // block_t), -(-v // block_v)
-    if t % block_t or v % block_v:
-        raise ValueError("fused_xent requires T, V divisible by block sizes")
-    kernel = functools.partial(_xent_kernel, block_t=block_t, block_v=block_v,
-                               n_v_blocks=nv)
-    return pl.pallas_call(
+    if t % block_t:
+        raise ValueError("fused_xent requires T divisible by block_t")
+    w = _pad_vocab(w, block_v)
+    nt, nv = t // block_t, w.shape[1] // block_v
+    kernel = functools.partial(_xent_kernel, block_v=block_v, n_v_blocks=nv,
+                               vocab=v)
+    loss = pl.pallas_call(
         kernel,
         grid=(nt, nv),
         in_specs=[
-            pl.BlockSpec((block_t,), lambda ti, vi: (ti,)),
+            pl.BlockSpec((block_t, 1), lambda ti, vi: (ti, 0)),
             pl.BlockSpec((block_t, d), lambda ti, vi: (ti, 0)),
             pl.BlockSpec((d, block_v), lambda ti, vi: (0, vi)),
         ],
-        out_specs=pl.BlockSpec((block_t,), lambda ti, vi: (ti,)),
-        out_shape=jax.ShapeDtypeStruct((t,), jnp.float32),
+        out_specs=pl.BlockSpec((block_t, 1), lambda ti, vi: (ti, 0)),
+        out_shape=jax.ShapeDtypeStruct((t, 1), jnp.float32),
         scratch_shapes=[
             pltpu.VMEM((block_t, 1), jnp.float32),
             pltpu.VMEM((block_t, 1), jnp.float32),
             pltpu.VMEM((block_t, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(labels, x, w)
+    )(labels.reshape(t, 1), x, w)
+    return loss[:, 0]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def fused_xent(x, w, labels, block_t=256, block_v=2048, interpret=False):
-    """Per-token loss (T,) fp32 for x: (T,D), w: (D,V), labels: (T,)."""
+def fused_xent(x, w, labels, block_t=256, block_v=512, interpret=False):
+    """Per-token loss (T,) fp32 for x: (T,D), w: (D,V), labels: (T,).  V need
+    not be a multiple of ``block_v``: the last vocab block is padded and
+    masked."""
     return _xent_forward(x, w, labels, block_t=block_t, block_v=block_v,
                          interpret=interpret)
 
@@ -100,14 +109,22 @@ def _bwd(block_t, block_v, interpret, res, g):
     x, w, labels = res
     t, d = x.shape
     v = w.shape[1]
+    block_v = min(block_v, v)
+    wp = _pad_vocab(w, block_v)
     xf = x.astype(jnp.float32)
-    # pass 1: global logsumexp per token (streamed)
-    n_blocks = -(-v // block_v)
+    n_blocks = wp.shape[1] // block_v
 
+    def block_logits(vi):
+        wb = jax.lax.dynamic_slice(wp, (0, vi * block_v), (d, block_v))
+        col = vi * block_v + jnp.arange(block_v)
+        logits = jnp.where(col[None, :] < v, xf @ wb.astype(jnp.float32),
+                           NEG_INF)
+        return wb, col, logits
+
+    # pass 1: global logsumexp per token (streamed)
     def lse_body(carry, vi):
         m, l = carry
-        wb = jax.lax.dynamic_slice(w, (0, vi * block_v), (d, block_v))
-        logits = xf @ wb.astype(jnp.float32)
+        _, _, logits = block_logits(vi)
         m_new = jnp.maximum(m, logits.max(axis=1))
         l = l * jnp.exp(m - m_new) + jnp.exp(logits - m_new[:, None]).sum(axis=1)
         return (m_new, l), None
@@ -120,10 +137,8 @@ def _bwd(block_t, block_v, interpret, res, g):
     # pass 2: accumulate grads block by block
     def grad_body(carry, vi):
         dx, dw = carry
-        wb = jax.lax.dynamic_slice(w, (0, vi * block_v), (d, block_v))
-        logits = xf @ wb.astype(jnp.float32)
+        wb, col, logits = block_logits(vi)
         p = jnp.exp(logits - lse[:, None])
-        col = vi * block_v + jnp.arange(block_v)
         p = p - (col[None, :] == labels[:, None]).astype(jnp.float32)
         p = p * g[:, None]
         dx = dx + p @ wb.astype(jnp.float32).T
@@ -132,9 +147,9 @@ def _bwd(block_t, block_v, interpret, res, g):
         return (dx, dw), None
 
     (dx, dw), _ = jax.lax.scan(
-        grad_body, (jnp.zeros((t, d), jnp.float32), jnp.zeros_like(w)),
+        grad_body, (jnp.zeros((t, d), jnp.float32), jnp.zeros_like(wp)),
         jnp.arange(n_blocks))
-    return dx.astype(x.dtype), dw, None
+    return dx.astype(x.dtype), dw[:, :v], None
 
 
 fused_xent.defvjp(_fwd, _bwd)

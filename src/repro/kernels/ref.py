@@ -60,14 +60,15 @@ def fused_xent_ref(x, w, labels):
 
 
 def dequant_rows_ref(codes, scales, *, block=256):
-    """(R, nb*block) int8 codes or (R, nb*block//2) uint8 nibble pairs, with
-    (R, nb) fp32 per-block scales -> (R, nb*block) fp32 rows."""
+    """(R, nb*block) int8 codes or (R, nb*block//2) uint8 nibble pairs (low
+    nibbles hold the first half of each block, high nibbles the second),
+    with (R, nb) fp32 per-block scales -> (R, nb*block) fp32 rows."""
+    r, nb = scales.shape
     if codes.dtype == jnp.uint8:
-        p = codes.astype(jnp.int32)
+        p = codes.astype(jnp.int32).reshape(r, nb, block // 2)
         lo, hi = p & 0xF, (p >> 4) & 0xF
         lo, hi = lo - 16 * (lo >> 3), hi - 16 * (hi >> 3)
-        codes = jnp.stack([lo, hi], axis=-1).reshape(codes.shape[0], -1)
-    r, nb = scales.shape
+        codes = jnp.concatenate([lo, hi], axis=-1)
     blocks = codes.reshape(r, nb, block).astype(jnp.float32)
     return (blocks * scales[..., None]).reshape(r, nb * block)
 

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
 Proves the distribution config is coherent without hardware: 512 placeholder
@@ -249,6 +246,8 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main() -> int:
     args = build_parser().parse_args()
+    from repro.launch.mesh import request_host_devices
+    request_host_devices(512)     # placeholder devices; before jax init
     RESULTS.mkdir(parents=True, exist_ok=True)
 
     if args.all:

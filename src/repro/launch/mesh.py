@@ -1,8 +1,13 @@
-"""Production mesh construction.
+"""Mesh construction: the one place this repo builds a ``jax.sharding.Mesh``.
 
 All mesh construction is behind functions (never module-level) so importing
 this module never touches jax device state — the dry-run must set XLA_FLAGS
 *before* any jax initialization.
+
+Every mesh gets ``AxisType.Auto`` axes: the runtime is written for GSPMD
+propagation plus ``shard_map`` over ``model``, not for explicit-sharding
+types (``jax.make_mesh``'s default), under which slicing a ``model``-sharded
+pool to a length the axis does not divide is a type error.
 
 Axis semantics:
   pod    outer data-parallel axis across pods (DCN); hierarchical all-reduce
@@ -11,27 +16,37 @@ Axis semantics:
 """
 from __future__ import annotations
 
+import os
+
 import jax
+from jax.sharding import AxisType
+
+_HOST_DEVICES_FLAG = "--xla_force_host_platform_device_count"
+
+
+def request_host_devices(n: int) -> None:
+    """Ask the CPU backend for ``n`` virtual devices by appending to the
+    user's ``XLA_FLAGS`` (never overwriting it; a count already given there
+    wins).  Takes effect only before jax initializes its backends, and
+    changes nothing on an accelerator."""
+    flags = os.environ.get("XLA_FLAGS", "")
+    if _HOST_DEVICES_FLAG not in flags:
+        os.environ["XLA_FLAGS"] = f"{flags} {_HOST_DEVICES_FLAG}={n}".strip()
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """Mesh of ``shape`` over ``axes`` with Auto axis types.  ``devices``
+    (default: all of this process's devices) picks a subset, e.g. the
+    survivors after an elastic re-plan."""
+    shape, axes = tuple(shape), tuple(axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh(shape, axes):
-    return jax.make_mesh(tuple(shape), tuple(axes))
-
-
-def make_host_mesh(n_data: int = 1, n_model: int = 1):
-    """Mesh over however many (possibly virtual) devices this host exposes."""
-    n = n_data * n_model
-    if len(jax.devices()) < n:
-        raise RuntimeError(
-            f"need {n} devices, have {len(jax.devices())}; set "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count={n} before jax init")
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return make_mesh(shape, axes)
 
 
 def data_axes(mesh) -> tuple:

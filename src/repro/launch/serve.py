@@ -20,12 +20,10 @@ def main() -> None:
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args()
 
-    import os
+    from repro.launch.mesh import request_host_devices
     n_data, n_model = (int(x) for x in args.mesh.split("x"))
     if n_data * n_model > 1:
-        os.environ.setdefault(
-            "XLA_FLAGS",
-            f"--xla_force_host_platform_device_count={n_data * n_model}")
+        request_host_devices(n_data * n_model)
 
     import jax
     import jax.numpy as jnp

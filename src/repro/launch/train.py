@@ -19,7 +19,22 @@ bit-identically to an uninterrupted one (``tests/test_train_resume.py``).
 from __future__ import annotations
 
 import argparse
+import os
 import time
+from pathlib import Path
+
+CHECKOUT = Path(__file__).resolve().parents[3]
+
+
+def use_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at a fixed directory and
+    return it: ``JAX_COMPILATION_CACHE_DIR`` when set (JAX reads it itself),
+    else ``<checkout>/.jax_cache``.  Call before the first compile."""
+    import jax
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          str(CHECKOUT / ".jax_cache"))
+    return jax.config.jax_compilation_cache_dir
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -74,7 +89,7 @@ def build_parser() -> argparse.ArgumentParser:
                          "simulate_plan and executes the certified winner — "
                          "never a higher simulated bubble than 'hand'")
     ap.add_argument("--lr", type=float, default=3e-4)
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
+    ap.add_argument("--ckpt-dir", default="checkpoints")
     ap.add_argument("--ckpt-every", "--save-every", type=int, default=50,
                     dest="ckpt_every",
                     help="save an atomic checkpoint every K steps; startup "
@@ -118,21 +133,23 @@ def build_parser() -> argparse.ArgumentParser:
     return ap
 
 
-def run_training(args) -> dict:
+def run_training(args, *, max_restarts: int = 3) -> dict:
     """The launcher body: build everything from ``args`` and train.
 
-    Returns ``{"state", "losses", "steps", "resumed_from"}`` so tests can
-    drive the exact production wiring (checkpoint resume included)
-    in-process.
+    Returns ``{"state", "losses", "steps", "resumed_from", "durations"}``
+    so tests can drive the exact production wiring (checkpoint resume
+    included) in-process; ``durations`` holds each step's wall seconds up
+    to its finished state.  ``max_restarts`` bounds the fault-tolerant
+    loop's checkpoint restarts (0: the first error propagates).
     """
-    import os
+    from repro.launch.mesh import request_host_devices
     n_data, n_model = (int(x) for x in args.mesh.split("x"))
     if n_data * n_model > 1:
-        os.environ.setdefault(
-            "XLA_FLAGS",
-            f"--xla_force_host_platform_device_count={n_data * n_model}")
+        request_host_devices(n_data * n_model)
 
     import jax
+
+    use_compile_cache()
 
     from repro.checkpoint import CheckpointManager, latest_step
     from repro.configs import smoke_config
@@ -302,16 +319,15 @@ def run_training(args) -> dict:
             step, state_sh, _ = build_train_step(
                 cfg, mesh, step_cfg, args.batch, args.seq,
                 round_major=rounds_data > 0)
+        # built in place, already sharded: no device holds the whole state
         if args.strategy == "roundpipe":
             from repro.core.dispatch import init_roundpipe_state
-            init = lambda: jax.device_put(
-                init_roundpipe_state(jax.random.PRNGKey(0), cfg, step_cfg,
-                                     n_workers=n_model),
-                state_sh)
+            init = jax.jit(lambda: init_roundpipe_state(
+                jax.random.PRNGKey(0), cfg, step_cfg, n_workers=n_model),
+                out_shardings=state_sh)
         else:
-            init = lambda: jax.device_put(
-                init_train_state(jax.random.PRNGKey(0), cfg, step_cfg),
-                state_sh)
+            init = jax.jit(lambda: init_train_state(
+                jax.random.PRNGKey(0), cfg, step_cfg), out_shardings=state_sh)
         like = jax.eval_shape(init)
 
         mgr = CheckpointManager(args.ckpt_dir, save_every=args.ckpt_every)
@@ -397,6 +413,7 @@ def run_training(args) -> dict:
                       flush=True)
 
         loop = FaultTolerantLoop(step, loop_mgr, loop_data,
+                                 max_restarts=max_restarts,
                                  step_timeout_s=600.0)
         t0 = time.time()
         state, final = loop.run(init, like, n_calls, shardings=state_sh,
@@ -410,7 +427,7 @@ def run_training(args) -> dict:
     else:
         print(f"done: {final} steps (all restored from checkpoint)")
     return {"state": state, "losses": losses, "steps": final,
-            "resumed_from": resumed_from}
+            "resumed_from": resumed_from, "durations": loop.durations}
 
 
 def _run_supervised(args, cfg, step_cfg, data, plan, mesh, n_data, n_model,
@@ -427,6 +444,7 @@ def _run_supervised(args, cfg, step_cfg, data, plan, mesh, n_data, n_model,
     import jax
     import numpy as np
 
+    from repro.launch.mesh import make_mesh
     from repro.runtime.supervisor import Supervisor, StragglerPolicy
 
     losses = []
@@ -436,9 +454,8 @@ def _run_supervised(args, cfg, step_cfg, data, plan, mesh, n_data, n_model,
         if n_workers == n_model:
             sub_mesh, rt_plan, m = mesh, plan, step_cfg.n_microbatches
         else:
-            devs = np.array(jax.devices()[:n_data * n_workers]).reshape(
-                n_data, n_workers)
-            sub_mesh = jax.sharding.Mesh(devs, ("data", "model"))
+            sub_mesh = make_mesh((n_data, n_workers), ("data", "model"),
+                                 devices=jax.devices()[:n_data * n_workers])
             rt_plan, m = replan.plan, replan.n_microbatches
             if args.batch % m:
                 raise SystemExit(

@@ -9,15 +9,13 @@ can annotate its preferred layouts unconditionally.
 from __future__ import annotations
 
 import jax
-from jax.sharding import PartitionSpec as P
-
-from repro.compat import bound_axis_names, get_abstract_mesh
+from jax.sharding import AxisType, PartitionSpec as P
 
 
 def current_mesh():
     from jax._src import mesh as mesh_lib
-    mesh = get_abstract_mesh()
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         mesh = mesh_lib.thread_resources.env.physical_mesh  # `with mesh:` form
     if mesh is None or mesh.empty:
         return None
@@ -30,11 +28,8 @@ def maybe_constrain(x, spec: P):
         return x
     sizes = dict(mesh.shape_tuple)
     # inside shard_map, manual axes cannot appear in sharding constraints
-    if mesh.axis_types is None:       # 0.4.x: no per-axis types; any bound
-        auto = set(mesh.axis_names) - bound_axis_names()  # axis may be manual
-    else:
-        auto = {name for name, kind in zip(mesh.axis_names, mesh.axis_types)
-                if str(kind).lower().endswith("auto")}
+    auto = {name for name, kind in zip(mesh.axis_names, mesh.axis_types)
+            if kind == AxisType.Auto}
     fixed = []
     for dim, ax in zip(x.shape, tuple(spec) + (None,) * (x.ndim - len(spec))):
         names = (ax,) if isinstance(ax, str) else tuple(ax or ())

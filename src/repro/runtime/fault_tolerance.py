@@ -21,8 +21,10 @@ from __future__ import annotations
 
 import dataclasses
 import statistics
+import sys
 import threading
 import time
+import traceback
 from typing import Callable, Optional
 
 
@@ -131,13 +133,17 @@ class FaultTolerantLoop:
                     batch = self.dataset.batch(step)
                     t0 = time.monotonic()
                     state, metrics = self.step_fn(state, batch)
-                    jax_block(metrics)
+                    jax_block((state, metrics))
                     dt = time.monotonic() - t0
                     hb.beat()
             except Exception:
+                traceback.print_exc()
                 self.restarts += 1
                 if self.restarts > self.max_restarts:
                     raise
+                print(f"step {step} failed; restart {self.restarts}/"
+                      f"{self.max_restarts} from the newest checkpoint",
+                      file=sys.stderr, flush=True)
                 ckpt_step = self.ckpt.restore_or_init(
                     lambda: init_fn(), like, shardings)
                 state, step = ckpt_step

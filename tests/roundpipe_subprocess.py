@@ -89,6 +89,7 @@ from repro.core.partition import LayerCost, Partition  # noqa: E402
 from repro.core.plan import (compile_plan, plan_from_config,  # noqa: E402
                              uniform_partition)
 from repro.core.simulator import simulate_plan  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.models import transformer as T  # noqa: E402
 from repro.models.config import get_config  # noqa: E402
 import dataclasses  # noqa: E402
@@ -272,7 +273,7 @@ def main():
     cfg = smoke_config(get_config(arch))
     cfg = dataclasses.replace(cfg, n_layers=n_layers, name=cfg.name + "-rp")
     n_model = 4
-    mesh = jax.make_mesh((2, n_model), ("data", "model"))
+    mesh = make_mesh((2, n_model), ("data", "model"))
     if mode in ("lora", "rounds-lora", "quant", "async-lora"):
         from repro.models.lora import LoraConfig
         LORA_CFG = LoraConfig(rank=4, alpha=8.0)
@@ -500,9 +501,8 @@ def run_chaos(cfg, mesh, plan, params, s):
             if n_workers == n0:
                 sub_mesh, rt_plan, m = mesh, plan, n0
             else:
-                sub_mesh = jax.sharding.Mesh(
-                    np.array(jax.devices()[:2 * n_workers]).reshape(
-                        2, n_workers), ("data", "model"))
+                sub_mesh = make_mesh((2, n_workers), ("data", "model"),
+                                     devices=jax.devices()[:2 * n_workers])
                 rt_plan, m = replan.plan, replan.n_microbatches
             scfg = StepConfig(strategy="roundpipe", grad_accum=1,
                               partition=rt_plan, n_microbatches=m,

@@ -12,6 +12,33 @@ from repro.core.consistency import (
     reference_staleness1,
 )
 
+from roundpipe_proof import run_mode
+
+
+# End-to-end subprocess proof(s) of this feature through the ring.
+# Kept first in the file: see tests/roundpipe_proof.py.
+@pytest.mark.slow     # a multi-minute XLA compile
+def test_dispatch_async_crossstep_matches_staleness1():
+    """Cross-step staleness-1 async optimizer: the
+    chained ring program — I optimizer steps in I*R*S + N - 1 ticks, step
+    T+1 injecting while step T's gradients drain into the in-program host
+    optimizer — on the uneven 7-layer/4-worker auto plan must per-leaf
+    allclose reference_staleness1 (and be distinguishable from the
+    staleness-0 trajectory), degenerate BIT-identically to the
+    synchronous loop with overlap disabled, and agree with the threaded
+    HostAsyncRoundPipe worker that drives the five per-layer §4.3
+    constraints around the real dispatch grads_fn."""
+    run_mode("qwen3-1.7b", "async", n_layers=7)
+
+
+@pytest.mark.slow     # a multi-minute XLA compile
+def test_dispatch_async_shallow_plan_parity():
+    """Shallow plan (3 layers on 4 workers: sf=1 < N-1): step k+1's fused
+    work starts BEFORE step k's deposit-complete tick, so the per-step
+    loss/replicated-grad accumulators must separate by work-step parity —
+    the chained program must still match reference_staleness1."""
+    run_mode("qwen3-1.7b", "async", n_layers=3)
+
 
 def make_workload(n_layers, jitter=0.0, seed=0):
     """Deterministic math, optional random sleeps to shake out races."""

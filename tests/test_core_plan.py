@@ -15,6 +15,36 @@ from repro.core.schedule import validate
 from repro.core.simulator import simulate, simulate_plan
 from repro.core.transfer import WindowPlan
 
+from roundpipe_proof import run_mode
+
+
+# End-to-end subprocess proof(s) of this feature through the ring.
+# Kept first in the file: see tests/roundpipe_proof.py.
+@pytest.mark.slow     # a multi-minute XLA compile
+def test_dispatch_auto_partition_matches_reference():
+    """Auto-partitioned uneven stages (incl. head-only fused slot)."""
+    run_mode("qwen3-1.7b", "auto")
+
+
+@pytest.mark.slow     # a multi-minute XLA compile
+def test_dispatch_auto_partition_nondivisible_layers():
+    """n_layers % n_workers != 0: the ring staggers by stage, not layer."""
+    run_mode("qwen3-1.7b", "auto", n_layers=7)
+
+
+@pytest.mark.slow     # a multi-minute XLA compile
+def test_dispatch_handmade_uneven_partition():
+    """Hand-built Partition with blocks of size 2/2/2+head/1/3 on L=6, N=4."""
+    run_mode("qwen3-1.7b", "uneven")
+
+
+@pytest.mark.slow     # a multi-minute XLA compile
+def test_dispatch_prefetch_matches_whole_block():
+    """Chunked double-buffered PrefetchProgram injection vs the monolithic
+    whole-block gather on an uneven plan (n_layers % N != 0): gradients and
+    loss must agree (and both must match the single-program reference)."""
+    run_mode("qwen3-1.7b", "prefetch", n_layers=7)
+
 
 def random_layers(rng, n):
     return [LayerCost(rng.uniform(0.5, 3.0), rng.uniform(0.5, 5.0),

@@ -107,7 +107,8 @@ class TestDecodeAttention:
 class TestFusedXent:
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     @pytest.mark.parametrize("t,d,v,bt,bv", [
-        (128, 32, 512, 64, 128), (256, 16, 1024, 256, 256), (64, 64, 256, 32, 64)])
+        (128, 32, 512, 64, 128), (256, 16, 1024, 256, 256), (64, 64, 256, 32, 64),
+        (64, 32, 300, 32, 128)])     # vocab not a multiple of block_v
     def test_forward_sweep(self, dtype, t, d, v, bt, bv):
         ks = jax.random.split(KEY, 2)
         x = rand(ks[0], (t, d), dtype)
@@ -120,12 +121,21 @@ class TestFusedXent:
                                    atol=3e-2 if dtype == jnp.bfloat16 else 1e-4)
 
     def test_gradients_match_reference(self):
-        t, d, v = 64, 16, 128
+        self._check_gradients(v=128, bv=32)
+
+    def test_gradients_ragged_vocab(self):
+        """Vocab not a multiple of block_v: padded columns must carry no
+        probability mass and no weight gradient."""
+        self._check_gradients(v=150, bv=64)
+
+    @staticmethod
+    def _check_gradients(v, bv):
+        t, d = 64, 16
         ks = jax.random.split(KEY, 2)
         x = rand(ks[0], (t, d), jnp.float32)
         w = rand(ks[1], (d, v), jnp.float32) * 0.1
         labels = jax.random.randint(KEY, (t,), 0, v)
-        gx, gw = jax.grad(lambda a, b: fused_xent(a, b, labels, 32, 32, True).sum(),
+        gx, gw = jax.grad(lambda a, b: fused_xent(a, b, labels, 32, bv, True).sum(),
                           argnums=(0, 1))(x, w)
         rx, rw = jax.grad(lambda a, b: ref.fused_xent_ref(a, b, labels).sum(),
                           argnums=(0, 1))(x, w)

@@ -10,8 +10,17 @@ pytestmark = pytest.mark.slow     # subprocess XLA compiles, minutes per case
 from repro.configs import ASSIGNED, PAPER_MODELS, smoke_config
 from repro.models import transformer as T
 from repro.models.config import get_config
+from roundpipe_proof import run_mode
 
 BATCH, SEQ = 2, 16
+
+
+# End-to-end subprocess proof(s) of this feature through the ring.
+# Kept first in the file: see tests/roundpipe_proof.py.
+@pytest.mark.parametrize("arch", ["qwen3-1.7b", "mixtral-8x7b", "rwkv6-7b",
+                                  "starcoder2-7b", "internvl2-76b"])
+def test_dispatch_matches_reference(arch):
+    run_mode(arch, "uniform")
 
 
 def make_batch(cfg, key):

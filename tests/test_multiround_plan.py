@@ -3,7 +3,7 @@ table both consumers follow, its agreement with the schedule generator's
 dispatch order, and the paper's bubble -> 0 claim as rounds grow.
 
 These are the fast-tier complements of the slow subprocess equivalence
-suites in ``test_roundpipe_dispatch.py`` (modes ``rounds`` /
+proofs in this file and ``test_models_lora.py`` (modes ``rounds`` /
 ``rounds-lora``), which prove the dispatch runtime executes this exact
 order numerically.
 """
@@ -17,6 +17,22 @@ from repro.core.plan import compile_plan, plan_from_config
 from repro.core.schedule import dispatch_slot_order, validate
 from repro.core.simulator import simulate_plan
 from repro.models.config import get_config
+
+from roundpipe_proof import run_mode
+
+
+# End-to-end subprocess proof(s) of this feature through the ring.
+# Kept first in the file: see tests/roundpipe_proof.py.
+@pytest.mark.slow     # a multi-minute XLA compile
+def test_dispatch_multiround_accumulation_matches_full_batch():
+    """Multi-round steady state: for R in {1, 2, 3} an
+    R-round gradient-accumulated step (M = R*N micro-batches stitched
+    back-to-back in R*S + N - 1 ticks) on the uneven 7-layer/4-worker auto
+    plan must per-leaf allclose a single-program full-batch reference over
+    the same M micro-batches, R = 1 must be BIT-identical to the legacy
+    single-round path, and the schedule generator must dispatch the exact
+    round-stitched tick order the runtime executes."""
+    run_mode("qwen3-1.7b", "rounds", n_layers=7)
 
 
 def random_plan(rng, n_layers=None, n_workers=None):
@@ -110,7 +126,7 @@ class TestCrossStepTickTable:
                 assert list(table[:live]) == [divmod(t, s)
                                               for t in range(live)]
                 assert list(table[live:]) == [None] * (n - 1)
-                # iterations=1 is exactly the PR-4 table
+                # iterations=1 is exactly the table
                 assert plan.tick_table(rounds, 1) == plan.tick_table(rounds)
 
     def test_rejects_nonpositive_iterations(self):

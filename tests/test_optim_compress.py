@@ -14,6 +14,22 @@ from hypothesis import strategies as st
 from repro.optim.compress import (BLOCK, compress_int8, decompress_int8,
                                   psum_compressed)
 
+from roundpipe_proof import run_mode
+
+
+# End-to-end subprocess proof(s) of this feature through the ring.
+# Kept first in the file: see tests/roundpipe_proof.py.
+@pytest.mark.slow     # a multi-minute XLA compile
+def test_dispatch_async_quant_matches_staleness1():
+    """Quantized pool + compressed deposits under the chained async program
+    (--pool-dtype/--grad-compress compose with --async-opt): the int8 ring — requantizing
+    the pool in-program at every update tick — must land on the
+    staleness-1 oracle taken at the int8-dequantized pool, separate from
+    staleness-0, and grad_compress='int8' must thread the error-feedback
+    residual through state['opt']['grad_residual'] across the chain while
+    staying within codec tolerance of the uncompressed chain."""
+    run_mode("qwen3-1.7b", "async-quant", n_layers=7)
+
 
 def _rand(shape, seed=0, scale=1.0):
     return scale * jax.random.normal(jax.random.PRNGKey(seed), shape,

@@ -15,6 +15,23 @@ from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 from repro.models.config import get_config
 
+from roundpipe_proof import run_mode
+
+
+# End-to-end subprocess proof(s) of this feature through the ring.
+# Kept first in the file: see tests/roundpipe_proof.py.
+@pytest.mark.slow     # a multi-minute XLA compile
+def test_dispatch_quant_pool_matches_reference():
+    """Quantized resident pool: int8 per-block-absmax
+    streaming with fused dequant-on-upload must track dequantize(quantize(W))
+    run dense to ~float tolerance (the codec IS the only perturbation), the
+    chunked code+scale prefetch must be BIT-identical to the whole-block
+    quant gather, the 4-bit packed frozen base must track its dequantized
+    reference under LoRA, plan byte accounting must match
+    quant_upload_bytes exactly, and the error-feedback int8 deposit must
+    telescope (mean error halves vs single-shot over K=4 repeats)."""
+    run_mode("qwen3-1.7b", "quant", n_layers=7)
+
 
 def _rows(r, e, seed=0, scale=3.0):
     return scale * jax.random.normal(jax.random.PRNGKey(seed), (r, e),
@@ -45,9 +62,14 @@ def test_quantize_dequant_roundtrip(bits, qmax, e):
 
 def test_pack_unpack_int4_inverse():
     codes = jnp.arange(-8, 8, dtype=jnp.int8).reshape(1, 16)
-    packed = dq.pack_int4(codes)
+    packed = dq.pack_int4(codes, block=8)
     assert packed.dtype == jnp.uint8 and packed.shape == (1, 8)
-    np.testing.assert_array_equal(np.asarray(dq.unpack_int4(packed)),
+    # low nibbles carry the first half of each block, high nibbles the second
+    np.testing.assert_array_equal(np.asarray(packed[0, :4]) & 0xF,
+                                  np.arange(-8, -4) & 0xF)
+    np.testing.assert_array_equal(np.asarray(packed[0, :4]) >> 4,
+                                  np.arange(-4, 0) & 0xF)
+    np.testing.assert_array_equal(np.asarray(dq.unpack_int4(packed, block=8)),
                                   np.asarray(codes))
 
 
@@ -68,6 +90,18 @@ def test_pallas_dequant_matches_ref(bits):
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(kref.dequant_rows_ref(codes, scales)),
                                rtol=0, atol=0)
+
+
+@pytest.mark.parametrize("bits", [8, 4])
+def test_pallas_dequant_edge_tiles_match_ref(bits):
+    """Rows and scale blocks that the kernel's tiles do not divide: the
+    partial edge tiles must still reproduce the reference exactly."""
+    rows = _rows(dq.ROW_TILE + 3, (dq.SCALE_TILE + 3) * QUANT_BLOCK - 5,
+                 seed=10)
+    codes, scales = dq.quantize_rows(rows, bits=bits)
+    out = dq.dequant_rows(codes, scales, interpret=True)
+    np.testing.assert_array_equal(
+        np.asarray(out), np.asarray(kref.dequant_rows_ref(codes, scales)))
 
 
 @pytest.mark.parametrize("out_dtype", [jnp.float32, jnp.bfloat16])

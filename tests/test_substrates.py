@@ -23,6 +23,21 @@ from repro.runtime.fault_tolerance import HeartbeatMonitor, StepHungError
 # optimizer
 # ---------------------------------------------------------------------------
 
+from roundpipe_proof import run_mode
+
+
+# End-to-end subprocess proof(s) of this feature through the ring.
+# Kept first in the file: see tests/roundpipe_proof.py.
+@pytest.mark.slow     # a multi-minute XLA compile
+def test_dispatch_async_lora_matches_staleness1():
+    """Async + frozen-base LoRA: the dense pool never
+    versions (base frozen), so only the adapter ring carries staleness-1
+    state — the chained program must per-leaf allclose the staleness-1
+    oracle run over adapters with a merged-dense device fn, separate from
+    staleness-0, and return base leaves bit-identical to init."""
+    run_mode("qwen3-1.7b", "async-lora", n_layers=7)
+
+
 class TestAdam:
     def _params(self):
         k = jax.random.PRNGKey(0)

@@ -3,7 +3,7 @@ mock step — detect→mitigate transitions for each fault class, the async
 re-plan refusal, the goodput ledger, the raising watchdog, and the async
 checkpoint writer's crash race — no XLA compiles, milliseconds per case.
 The real compiled step goes through the same paths in the slow-tier
-``chaos`` subprocess mode (tests/test_roundpipe_dispatch.py)."""
+``chaos`` subprocess proof at the top of this file."""
 import dataclasses
 import itertools
 import threading
@@ -21,6 +21,25 @@ from repro.runtime.fault_tolerance import (FaultTolerantLoop,
 from repro.runtime.supervisor import (GoodputMeter, Supervisor, WorkerFault,
                                       analytic_goodput,
                                       checkpoint_cost_model)
+
+from roundpipe_proof import run_mode
+
+
+# End-to-end subprocess proof(s) of this feature through the ring.
+# Kept first in the file: see tests/roundpipe_proof.py.
+@pytest.mark.slow     # a multi-minute XLA compile
+def test_supervisor_chaos_harness():
+    """Goodput supervisor chaos harness: the REAL
+    compiled step driven through the full detect→mitigate state machine on
+    the uneven 7-layer/4-worker auto plan.  A 5x-slowed worker must
+    trigger the straggler streak → device_scale re-score → g0=3 rotation
+    rebuild; a killed worker must trigger the elastic re-plan to N-1=3
+    (fresh auto partition, M' floored to 3) + restore of the newest
+    async-written checkpoint onto the (2,3) mesh.  Final params must match
+    the uninterrupted N=4 reference trajectory, the replayed step's loss
+    must reproduce its pre-fault value (deterministic replay), and the
+    goodput ledger must charge nonzero replay + replan overhead."""
+    run_mode("qwen3-1.7b", "chaos", n_layers=7)
 
 
 def fake_clock():

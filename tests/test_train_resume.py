@@ -58,3 +58,35 @@ def test_fresh_dir_starts_from_scratch(tmp_path):
     out = run_training(_args(tmp_path / "fresh", 2))
     assert out["resumed_from"] is None
     assert len(out["losses"]) == 2
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_location(tmp_path, from_env):
+    """``use_compile_cache``: JAX_COMPILATION_CACHE_DIR when set, else the
+    fixed ``<checkout>/.jax_cache``; a fresh compile lands there."""
+    import os
+    import subprocess
+    import sys
+
+    from repro.launch.train import CHECKOUT
+
+    want = tmp_path / "cache" if from_env else CHECKOUT / ".jax_cache"
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["PYTHONPATH"] = str(CHECKOUT / "src")
+    env["JAX_PLATFORMS"] = "cpu"
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(want)
+    before = set(want.iterdir()) if want.is_dir() else set()
+    code = (
+        "import jax\n"
+        "from repro.launch.train import use_compile_cache\n"
+        "jax.config.update('jax_persistent_cache_min_compile_time_secs', 0)\n"
+        "print(use_compile_cache())\n"
+        f"jax.jit(lambda x: x * {hash(str(tmp_path)) % 10**6 + 1})(1.0)"
+        ".block_until_ready()\n")
+    r = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert r.stdout.split() == [str(want)]
+    assert set(want.iterdir()) - before, f"no cache entry written to {want}"
