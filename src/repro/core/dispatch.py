@@ -105,6 +105,7 @@ from repro.models.config import ModelConfig
 from repro.optim import (apply_updates, init_opt_state, merge_trainable,
                          opt_state_specs, trainable_leaves)
 from repro.launch.mesh import axis_size
+from repro.runtime import spans
 
 
 def _check_program(program, plan, rounds: int, iterations: int):
@@ -132,6 +133,7 @@ def _check_program(program, plan, rounds: int, iterations: int):
     return program
 
 
+@spans.scope(spans.RING)
 def roundpipe_forward_backward(params, batch, worker_id, grad_residual=None,
                                *, cfg: ModelConfig,
                                plan, n_workers: int, l_pad: int,
@@ -373,6 +375,7 @@ def roundpipe_forward_backward(params, batch, worker_id, grad_residual=None,
         start = starts_arr[slot_i]
         n_act = sizes_arr[slot_i]
 
+        @spans.scope(spans.SLOT_F)
         def do_plain(op):
             act_, stash_ = op
             # frozen-base: forward compute runs on the merged weights; merged
@@ -386,9 +389,10 @@ def roundpipe_forward_backward(params, batch, worker_id, grad_residual=None,
                 active = k < n_act
                 lid = jnp.where(active, jnp.minimum(start + k, l_total),
                                 l_total)                  # row L = scratch
-                st_ = jax.lax.dynamic_update_slice(
-                    st_, xc[None].astype(st_.dtype),
-                    (lid,) + (jnp.int32(0),) * len(bshape))
+                with jax.named_scope(spans.STASH):
+                    st_ = jax.lax.dynamic_update_slice(
+                        st_, xc[None].astype(st_.dtype),
+                        (lid,) + (jnp.int32(0),) * len(bshape))
                 y = T.layer_forward(xc, lw, cfg, kv_chunk=kv_chunk)
                 return jnp.where(active, y, xc), st_
 
@@ -411,6 +415,7 @@ def roundpipe_forward_backward(params, batch, worker_id, grad_residual=None,
             # frozen base: differentiate through the adapter operand only —
             # the vjp emits ADAPTER-shaped block grads; dense/head/norm/embed
             # cotangents are never formed
+            @spans.scope(spans.SLOT_FB)
             def do_fused(op):
                 act_, ls, tc, gcarry, gb_ = op
                 x_in = jnp.where(round_start, round_leaf(x_emb, ri),
@@ -432,10 +437,13 @@ def roundpipe_forward_backward(params, batch, worker_id, grad_residual=None,
                 fused_on, do_fused, lambda op: op,
                 (act, loss_sum, tok_count, grad_carry, gbuf))
 
+            @spans.scope(spans.SLOT_B)
             def do_bwd(op):
                 gcarry, gb_ = op
-                x_in = jax.lax.dynamic_index_in_dim(
-                    stash, jnp.minimum(start, l_total), 0, keepdims=False)
+                with jax.named_scope(spans.STASH):
+                    x_in = jax.lax.dynamic_index_in_dim(
+                        stash, jnp.minimum(start, l_total), 0,
+                        keepdims=False)
                 y, vjp = jax.vjp(
                     lambda ablk, xx: rm.stage_fwd(
                         lora_mod.merge_layers(ring, ablk, lora), n_act, xx),
@@ -447,6 +455,7 @@ def roundpipe_forward_backward(params, batch, worker_id, grad_residual=None,
             grad_carry, gbuf = jax.lax.cond(
                 bwd_on, do_bwd, lambda op: op, (grad_carry, gbuf))
         else:
+            @spans.scope(spans.SLOT_FB)
             def do_fused(op):
                 act_, ls, tc, gcarry, hg, fg, gb_, eg = op
                 x_in = jnp.where(round_start, round_leaf(x_emb, ri),
@@ -473,10 +482,13 @@ def roundpipe_forward_backward(params, batch, worker_id, grad_residual=None,
                 (act, loss_sum, tok_count, grad_carry, head_grad, fnorm_grad,
                  gbuf, embed_grad))
 
+            @spans.scope(spans.SLOT_B)
             def do_bwd(op):
                 gcarry, gb_, eg = op
-                x_in = jax.lax.dynamic_index_in_dim(
-                    stash, jnp.minimum(start, l_total), 0, keepdims=False)
+                with jax.named_scope(spans.STASH):
+                    x_in = jax.lax.dynamic_index_in_dim(
+                        stash, jnp.minimum(start, l_total), 0,
+                        keepdims=False)
                 y, vjp = jax.vjp(lambda blk, xx: rm.stage_fwd(blk, n_act, xx),
                                  ring, x_in)
                 gb, gx = vjp(gcarry.astype(y.dtype))
@@ -538,6 +550,7 @@ def roundpipe_forward_backward(params, batch, worker_id, grad_residual=None,
     return grads, loss_sum * scale, tok_count
 
 
+@spans.scope(spans.RING)
 def roundpipe_async_forward_backward(params, opt_state, batch, worker_id,
                                      cfg: ModelConfig, *, plan, n_workers: int,
                                      l_pad: int, steps: int, rounds: int,
@@ -816,6 +829,7 @@ def roundpipe_async_forward_backward(params, opt_state, batch, worker_id,
                 return sel2(x_emb_all, step_tr, ri)
             return sel2(x_emb_pair, parity, ri)
 
+        @spans.scope(spans.SLOT_F)
         def do_plain(op):
             act_, stash_ = op
             eff_ring = lora_mod.merge_layers(ring, a_ring, lora) \
@@ -826,9 +840,10 @@ def roundpipe_async_forward_backward(params, opt_state, batch, worker_id,
                 active = k < n_act
                 lid = jnp.where(active, jnp.minimum(start + k, l_total),
                                 l_total)
-                st_ = jax.lax.dynamic_update_slice(
-                    st_, xc[None].astype(st_.dtype),
-                    (lid,) + (jnp.int32(0),) * len(bshape))
+                with jax.named_scope(spans.STASH):
+                    st_ = jax.lax.dynamic_update_slice(
+                        st_, xc[None].astype(st_.dtype),
+                        (lid,) + (jnp.int32(0),) * len(bshape))
                 y = T.layer_forward(xc, lw, cfg, kv_chunk=kv_chunk)
                 return jnp.where(active, y, xc), st_
 
@@ -850,6 +865,7 @@ def roundpipe_async_forward_backward(params, opt_state, batch, worker_id,
         if frozen:
             # frozen base: differentiate through the adapter ring only —
             # replicated params are constants, no parity selection needed
+            @spans.scope(spans.SLOT_FB)
             def do_fused(op):
                 act_, ls, tc, gcarry, gb_ = op
                 x_in = jnp.where(round_start, x_emb_cur(), act_)
@@ -870,10 +886,13 @@ def roundpipe_async_forward_backward(params, opt_state, batch, worker_id,
                 fused_on, do_fused, lambda op: op,
                 (act, loss_sum, tok_count, grad_carry, gbuf))
 
+            @spans.scope(spans.SLOT_B)
             def do_bwd(op):
                 gcarry, gb_ = op
-                x_in = jax.lax.dynamic_index_in_dim(
-                    stash, jnp.minimum(start, l_total), 0, keepdims=False)
+                with jax.named_scope(spans.STASH):
+                    x_in = jax.lax.dynamic_index_in_dim(
+                        stash, jnp.minimum(start, l_total), 0,
+                        keepdims=False)
                 y, vjp = jax.vjp(
                     lambda ablk, xx: rm.stage_fwd(
                         lora_mod.merge_layers(ring, ablk, lora), n_act, xx),
@@ -885,6 +904,7 @@ def roundpipe_async_forward_backward(params, opt_state, batch, worker_id,
             grad_carry, gbuf = jax.lax.cond(
                 bwd_on, do_bwd, lambda op: op, (grad_carry, gbuf))
         else:
+            @spans.scope(spans.SLOT_FB)
             def do_fused(op):
                 act_, ls, tc, gcarry, hg, fg, gb_, eg = op
                 x_in = jnp.where(round_start, x_emb_cur(), act_)  # Sf==0 edge
@@ -916,10 +936,13 @@ def roundpipe_async_forward_backward(params, opt_state, batch, worker_id,
                 (act, loss_sum, tok_count, grad_carry, head_grad, fnorm_grad,
                  gbuf, embed_grad))
 
+            @spans.scope(spans.SLOT_B)
             def do_bwd(op):
                 gcarry, gb_, eg = op
-                x_in = jax.lax.dynamic_index_in_dim(
-                    stash, jnp.minimum(start, l_total), 0, keepdims=False)
+                with jax.named_scope(spans.STASH):
+                    x_in = jax.lax.dynamic_index_in_dim(
+                        stash, jnp.minimum(start, l_total), 0,
+                        keepdims=False)
                 y, vjp = jax.vjp(lambda blk, xx: rm.stage_fwd(blk, n_act, xx),
                                  ring, x_in)
                 gb, gx = vjp(gcarry.astype(y.dtype))

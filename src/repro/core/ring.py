@@ -32,6 +32,10 @@ chunk tables, leaf shapes); traced operands flow through method arguments,
 so constructing one inside a ``shard_map`` body is free and the emitted ops
 are identical to the pre-refactor closures — the subprocess equivalence
 matrix asserts the sync path bit-exactly.
+
+Each primitive traces under its ``rp.*`` scope (``runtime/spans.py``:
+``rp.shift``, ``rp.upload``, ``rp.promote``, ``rp.quantize``,
+``rp.deposit``), so both drivers name their ops from this one place.
 """
 from __future__ import annotations
 
@@ -48,6 +52,7 @@ from repro.models import transformer as T
 from repro.models.config import ModelConfig
 from repro.models.layers import apply_norm
 from repro.optim.compress import compress_int8, decompress_int8
+from repro.runtime import spans
 
 AXIS = "model"
 
@@ -231,6 +236,7 @@ class RingMachine:
         self.row_elems = sum(self.leaf_elems)
 
     # ---- ring hop ----------------------------------------------------------
+    @spans.scope(spans.SHIFT)
     def shift(self, tree):
         """One open-ring hop: every row moves one logical position up the
         ring (logical N-1 exits); ``g0`` decides where that lives
@@ -269,6 +275,7 @@ class RingMachine:
         return tot, cnt
 
     # ---- dense payload codec -----------------------------------------------
+    @spans.scope(spans.PROMOTE)
     def assemble_block(self, spec, src_pool):
         """Gather slot ``spec``'s layers from their pool owners to the
         injection worker (physical ``self.inj``, logical 0 — static
@@ -295,6 +302,7 @@ class RingMachine:
         return (cu.lo * self.row_elems // cu.parent_bytes,
                 cu.hi * self.row_elems // cu.parent_bytes)
 
+    @spans.scope(spans.UPLOAD)
     def upload_slot(self, stand, slot_idx, pool_leaves):
         """Stream slot ``slot_idx``'s chunks into the standby leaves, one
         ppermute per (chunk x overlapped leaf), in LPT window order.  The
@@ -319,6 +327,7 @@ class RingMachine:
                     stand[i].shape)
         return stand
 
+    @spans.scope(spans.PROMOTE)
     def promote_standby(self, stand, spec):
         """Standby -> injection block: replicate row 0 into padding rows
         (same real-weight padding as ``assemble_block``)."""
@@ -331,11 +340,13 @@ class RingMachine:
             leaves.append(l)
         return jax.tree_util.tree_unflatten(self.pool_def, leaves)
 
+    @spans.scope(spans.UPLOAD)
     def zeros_standby(self):
         return [jnp.zeros((self.kmax,) + s, d)
                 for s, d in zip(self.leaf_shapes, self.leaf_dtypes)]
 
     # ---- quantized payload codec -------------------------------------------
+    @spans.scope(spans.QUANTIZE)
     def quantize_pool(self, pool):
         """One quantization pass over a LOCAL pool shard: the "host-side"
         codes+scales image whose bytes the up lane ships
@@ -350,11 +361,13 @@ class RingMachine:
             [l.reshape(self.per, -1) for l in leaves], axis=1)
         return quantize_rows(pool_cat, bits=POOL_DTYPE_BITS[self.pool_dtype])
 
+    @spans.scope(spans.UPLOAD)
     def zeros_standby_q(self, qpair):
         q_codes, q_scales = qpair
         return (jnp.zeros((self.kmax, q_codes.shape[1]), q_codes.dtype),
                 jnp.zeros((self.kmax, q_scales.shape[1]), jnp.float32))
 
+    @spans.scope(spans.UPLOAD)
     def upload_slot_q(self, stand, slot_idx, qpair):
         """Quantized standby fill: each ChunkUpload's plan-byte range maps
         proportionally onto the CODE columns (endpoints are exact, so chunk
@@ -382,6 +395,7 @@ class RingMachine:
                 scales = scales.at[cu.row].set(srow)
         return codes, scales
 
+    @spans.scope(spans.PROMOTE)
     def dequant_block(self, codes, scales, spec):
         """Fused dequant-on-upload: codes+scales -> injection block in the
         pool's compute dtype (``kernels.ops.dequant_rows``), split back into
@@ -401,6 +415,7 @@ class RingMachine:
                                      self.leaf_offs, self.leaf_elems)]
         return jax.tree_util.tree_unflatten(self.pool_def, leaves)
 
+    @spans.scope(spans.PROMOTE)
     def assemble_block_q(self, spec, qpair):
         """Whole-block fallback, quantized: gather full code+scale rows from
         their owners, then one fused dequant."""
@@ -419,6 +434,7 @@ class RingMachine:
         return self.dequant_block(jnp.stack(crows), jnp.stack(srows), spec)
 
     # ---- gradient deposits (slot exits the ring at logical worker N-1) -----
+    @spans.scope(spans.DEPOSIT)
     def deposit_plain(self, pool_grads, row, owner, idx):
         """Exact fp32 deposit: the fully ring-reduced row crosses the down
         lane tail -> owner and sums into the owner's accumulator row
@@ -429,6 +445,7 @@ class RingMachine:
             lambda pg, ar: pg.at[idx].add(ar.astype(jnp.float32)),
             pool_grads, arriving)
 
+    @spans.scope(spans.DEPOSIT)
     def deposit_ef(self, pg_tree, res_tree, row, owner, idx):
         """Error-feedback int8 deposit (DESIGN.md §7).  The tail worker
         compresses the fully ring-reduced row PLUS the row's carried

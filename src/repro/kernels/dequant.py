@@ -116,7 +116,9 @@ def dequant_rows(codes, scales, *, block: int = QUANT_BLOCK,
     codes int8 selects 8-bit codes; uint8 the packed int4 pairs (the storage
     dtype IS the format tag).  Each program dequantizes a tile of
     ``ROW_TILE`` rows by ``SCALE_TILE`` scale blocks, so every block's last
-    two dims meet the TPU's (8, 128) tiling; edge tiles are partial.
+    two dims meet the TPU's (8, 128) tiling; edge tiles are partial.  The
+    kernel's ``name`` names its HLO instruction (``dequant_rows.N``) whatever
+    scope or function calls it; the benchmark finds its events by it.
     """
     r, nb = scales.shape
     packed = codes.dtype == jnp.uint8
@@ -138,4 +140,5 @@ def dequant_rows(codes, scales, *, block: int = QUANT_BLOCK,
         out_specs=pl.BlockSpec((tr, tb * block), lambda ri, bi: (ri, bi)),
         out_shape=jax.ShapeDtypeStruct((r, nb * block), out_dtype),
         interpret=interpret,
+        name="dequant_rows",
     )(codes, scales)

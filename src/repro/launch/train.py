@@ -130,6 +130,14 @@ def build_parser() -> argparse.ArgumentParser:
                          "--elastic a persistent per-worker straggler "
                          "triggers the g0 rotation mitigation")
     ap.add_argument("--log-every", type=int, default=10)
+    ap.add_argument("--profile-dir", default=None,
+                    help="trace the --profile-steps steps with jax.profiler "
+                         "into DIR (TensorBoard / Perfetto): each step is an "
+                         "rp.train_step, the device ops carry the step's "
+                         "rp.* scopes, GC pauses show as rp.host.gc")
+    ap.add_argument("--profile-steps", default="2:5",
+                    help="A:B — the loop steps --profile-dir traces, A to "
+                         "B-1 (chained calls under --async-opt)")
     return ap
 
 
@@ -159,8 +167,15 @@ def run_training(args, *, max_restarts: int = 3) -> dict:
                                     init_train_state)
     from repro.models.config import get_config
     from repro.optim import OptConfig
-    from repro.runtime import FaultTolerantLoop
+    from repro.runtime import FaultTolerantLoop, spans
 
+    profile = None
+    if args.profile_dir:
+        try:
+            profile = spans.ProfileWindow.parse(args.profile_dir,
+                                                args.profile_steps)
+        except ValueError as e:
+            raise SystemExit(str(e)) from None
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = smoke_config(cfg)
@@ -303,8 +318,12 @@ def run_training(args, *, max_restarts: int = 3) -> dict:
               f"{args.ckpt_dir}")
 
     if use_supervisor:
-        return _run_supervised(args, cfg, step_cfg, data, plan,
-                               mesh, n_data, n_model, resumed_from)
+        try:
+            return _run_supervised(args, cfg, step_cfg, data, plan, mesh,
+                                   n_data, n_model, resumed_from, profile)
+        finally:
+            if profile is not None:
+                profile.close()
 
     with mesh:
         if async_rp:
@@ -414,10 +433,14 @@ def run_training(args, *, max_restarts: int = 3) -> dict:
 
         loop = FaultTolerantLoop(step, loop_mgr, loop_data,
                                  max_restarts=max_restarts,
-                                 step_timeout_s=600.0)
+                                 step_timeout_s=600.0, profile=profile)
         t0 = time.time()
-        state, final = loop.run(init, like, n_calls, shardings=state_sh,
-                                metrics_cb=metrics_cb)
+        try:
+            state, final = loop.run(init, like, n_calls, shardings=state_sh,
+                                    metrics_cb=metrics_cb)
+        finally:
+            if profile is not None:
+                profile.close()
         final *= steps_per_call
         dt = time.time() - t0
     if losses:
@@ -431,7 +454,7 @@ def run_training(args, *, max_restarts: int = 3) -> dict:
 
 
 def _run_supervised(args, cfg, step_cfg, data, plan, mesh, n_data, n_model,
-                    resumed_from) -> dict:
+                    resumed_from, profile=None) -> dict:
     """The --elastic / --async-ckpt path: the goodput supervisor drives the
     compiled step through a runtime factory, so a worker death rebuilds the
     whole stack (plan, mesh, step, shardings) for the survivors and resumes
@@ -526,7 +549,8 @@ def _run_supervised(args, cfg, step_cfg, data, plan, mesh, n_data, n_model,
                      replan_fn=replan_fn,
                      straggler=StragglerPolicy(factor=args.straggler_factor),
                      save_every=args.ckpt_every,
-                     async_ckpt=args.async_ckpt, step_timeout_s=600.0)
+                     async_ckpt=args.async_ckpt, step_timeout_s=600.0,
+                     profile=profile)
     t0 = time.time()
     state, final = sup.run(args.steps)
     dt = time.time() - t0

@@ -37,6 +37,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro.runtime import spans
+
 DEFAULT_TARGETS = ("attn", "mlp")
 
 
@@ -180,6 +182,7 @@ def _delta(pair, w, scale):
     return d.reshape(w.shape).astype(w.dtype)
 
 
+@spans.scope(spans.MERGE)
 def merge_layers(layers, adapters, cfg: LoraConfig, *, sign: float = 1.0):
     """``W + sign * (alpha/r) * B @ A`` leafwise.  Works on any tree with the
     pool's structure and a shared leading axis — the full stacked pool, a

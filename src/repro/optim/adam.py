@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.runtime import spans
+
 
 @dataclasses.dataclass(frozen=True)
 class OptConfig:
@@ -64,6 +66,7 @@ def trainable_leaves(tree, mask):
     return tree if mask else None
 
 
+@spans.scope(spans.OPTIMIZER)
 def merge_trainable(full, trainable, mask):
     """Inverse of :func:`trainable_leaves`: graft updated trainable leaves
     back into the full tree; mask-False leaves pass through untouched."""
@@ -110,6 +113,7 @@ def global_grad_norm(grads):
                         for g in jax.tree.leaves(grads)))
 
 
+@spans.scope(spans.OPTIMIZER)
 def apply_updates(opt_state, grads, cfg: OptConfig, param_like=None,
                   grad_norm=None):
     """Returns (new_params, new_opt_state, metrics).

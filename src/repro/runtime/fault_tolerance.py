@@ -27,6 +27,8 @@ import time
 import traceback
 from typing import Callable, Optional
 
+from . import spans
+
 
 @dataclasses.dataclass
 class StragglerPolicy:
@@ -104,13 +106,15 @@ class FaultTolerantLoop:
     def __init__(self, step_fn, ckpt_manager, dataset, *,
                  straggler: StragglerPolicy = StragglerPolicy(),
                  max_restarts: int = 3,
-                 step_timeout_s: float = 3600.0):
+                 step_timeout_s: float = 3600.0,
+                 profile: Optional[spans.ProfileWindow] = None):
         self.step_fn = step_fn
         self.ckpt = ckpt_manager
         self.dataset = dataset
         self.policy = straggler
         self.max_restarts = max_restarts
         self.step_timeout_s = step_timeout_s
+        self.profile = profile
         self.stragglers: list[int] = []
         self.restarts = 0
         self.durations: list[float] = []
@@ -129,7 +133,8 @@ class FaultTolerantLoop:
         step = start
         while step < n_steps:
             try:
-                with HeartbeatMonitor(self.step_timeout_s) as hb:
+                with HeartbeatMonitor(self.step_timeout_s) as hb, \
+                        spans.train_step(step, self.profile):
                     batch = self.dataset.batch(step)
                     t0 = time.monotonic()
                     state, metrics = self.step_fn(state, batch)

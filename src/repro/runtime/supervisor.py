@@ -57,6 +57,7 @@ import time
 import warnings
 from typing import Any, Callable, Optional
 
+from . import spans
 from .fault_tolerance import (HeartbeatMonitor, StepHungError,
                               StragglerPolicy, jax_block)
 
@@ -169,7 +170,8 @@ class Supervisor:
                  save_every: int = 10, keep: int = 3,
                  async_ckpt: bool = True, use_async: bool = False,
                  step_timeout_s: float = 3600.0, max_restarts: int = 3,
-                 clock: Callable[[], float] = time.monotonic):
+                 clock: Callable[[], float] = time.monotonic,
+                 profile: Optional[spans.ProfileWindow] = None):
         self.factory = factory
         self.ckpt_dir = ckpt_dir
         self.n_workers = n_workers
@@ -182,6 +184,7 @@ class Supervisor:
         self.step_timeout_s = step_timeout_s
         self.max_restarts = max_restarts
         self.clock = clock
+        self.profile = profile
         self.g0 = 0
         self.meter = GoodputMeter()
         self.events: list[SupervisorEvent] = []
@@ -332,7 +335,8 @@ class Supervisor:
             while step < n_steps:
                 t0 = self.clock()
                 try:
-                    with HeartbeatMonitor(self.step_timeout_s) as hb:
+                    with HeartbeatMonitor(self.step_timeout_s) as hb, \
+                            spans.train_step(step, self.profile):
                         batch = runtime.batch_for(step)
                         state, metrics = runtime.step_fn(state, batch)
                         jax_block(metrics)

@@ -8,6 +8,7 @@ pytest worker collects the same tests and only the worker given this file
 loads the TPU compiler.
 """
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -62,6 +63,25 @@ def test_dequant_rows_compiles_at_pool_width(one_chip, bits):
     scales = jax.ShapeDtypeStruct((rows, nb), jnp.float32, sharding=one_chip)
     _compile(lambda c, s: dequant.dequant_rows(c, s, out_dtype=jnp.bfloat16),
              codes, scales)
+
+
+def test_dequant_rows_instruction_keeps_its_name(one_chip):
+    """The kernel's HLO instruction is ``dequant_rows.N`` (what the
+    benchmark's ``dequant_rows_roofline`` finds in the trace) even when it
+    is called, as the ring calls it, from another function inside an
+    ``rp.*`` scope: without the kernel's own ``name`` the instruction takes
+    the innermost scope's name."""
+    codes = jax.ShapeDtypeStruct((8, 2048), jnp.int8, sharding=one_chip)
+    scales = jax.ShapeDtypeStruct((8, 8), jnp.float32, sharding=one_chip)
+
+    def promote(c, s):
+        with jax.named_scope("rp.promote"):
+            return dequant.dequant_rows(c, s, out_dtype=jnp.bfloat16)
+
+    text = _compile(promote, codes, scales).as_text()
+    calls = re.findall(r"%([\w.-]+) = \S+ custom-call\(", text)
+    assert calls and all(re.fullmatch(r"dequant_rows(\.\d+)?", c)
+                         for c in calls), calls
 
 
 def test_flash_attention_compiles_at_model_width(one_chip):
