@@ -142,7 +142,8 @@ def roundpipe_forward_backward(params, batch, worker_id, grad_residual=None,
                                prefetch_program=None, lora=None,
                                rounds=None, pool_dtype: str = "none",
                                grad_compress: str = "none",
-                               tick_program=None, g0: int = 0):
+                               tick_program=None, g0: int = 0,
+                               injections: dict | None = None):
     """Synchronous driver: unrolls a :class:`~repro.core.schedule.TickProgram`
     over the shared :class:`~repro.core.ring.RingMachine` (source pool = the
     live pool, accumulators = the per-step family) and returns
@@ -202,6 +203,11 @@ def roundpipe_forward_backward(params, batch, worker_id, grad_residual=None,
     across rotations (every worker still sweeps every slot with its own
     resident group); ``g0=0`` emits bit-identical programs to the legacy
     path.
+
+    On a one-worker ring every slot is a ``RingMachine.local_slot``: its
+    injection block is read in place from the local pool, with no standby
+    and no gather.  ``injections`` (a dict) receives the trace's count of
+    ``local`` and ``streamed`` injections.
     """
     n = n_workers
     frozen = lora is not None
@@ -229,7 +235,7 @@ def roundpipe_forward_backward(params, batch, worker_id, grad_residual=None,
                      worker_id=worker_id, pool_template=pool,
                      xent_chunk=xent_chunk, kv_chunk=kv_chunk,
                      prefetch_program=prefetch_program, pool_dtype=pool_dtype,
-                     g0=g0)
+                     g0=g0, injections=injections)
     A = StepAccum                          # per-step accumulator family
     pslot = None                           # ignored by the per-step family
     head_w = T.lm_head_weights(params, cfg)
@@ -309,7 +315,7 @@ def roundpipe_forward_backward(params, batch, worker_id, grad_residual=None,
             return rm.dequant_block(stand[0], stand[1], spec)
         return rm.promote_standby(stand, spec)
 
-    if prefetch_program is not None:
+    if rm.uses_standby(0):
         # fill prologue: slot 0 has no preceding compute window to hide in
         standby = _upload(_zeros(), 0)
 
@@ -327,22 +333,22 @@ def roundpipe_forward_backward(params, batch, worker_id, grad_residual=None,
             a_shifted = rm.shift(a_ring)
         if entry is not None:
             spec = slots[entry[1]]
-            if prefetch_program is not None:
+            if rm.uses_standby(entry[1]):
                 if spec.size:
                     ring = ring_add(shifted, _promote(standby, spec))
                 else:
                     ring = shifted
-                # double-buffer swap: the next tick's slot streams into the
-                # fresh standby across THIS tick's compute windows (XLA
-                # overlaps the copies with the compute below — no
-                # tick-boundary burst).  Round r+1's slot-0 upload therefore
-                # streams while round r drains its deepest slots: the
-                # per-slot ChunkUpload tables are replayed modulo S.
-                if rec.upload is not None:
-                    standby = _upload(_zeros(), rec.upload[0])
             else:
                 inj = _assemble(spec)
                 ring = ring_add(shifted, inj) if inj is not None else shifted
+            # double-buffer swap: the next tick's slot streams into the
+            # fresh standby across THIS tick's compute windows (XLA
+            # overlaps the copies with the compute below — no tick-boundary
+            # burst).  Round r+1's slot-0 upload therefore streams while
+            # round r drains its deepest slots: the per-slot ChunkUpload
+            # tables are replayed modulo S.
+            if rec.upload is not None and rm.uses_standby(rec.upload[0]):
+                standby = _upload(_zeros(), rec.upload[0])
             if frozen:
                 # adapters are ~100-1000x smaller than the dense block: the
                 # whole-block gather is already far below one chunk upload,
@@ -560,7 +566,8 @@ def roundpipe_async_forward_backward(params, opt_state, batch, worker_id,
                                      prefetch_program=None, lora=None,
                                      pool_dtype: str = "none",
                                      grad_compress: str = "none",
-                                     tick_program=None, g0: int = 0):
+                                     tick_program=None, g0: int = 0,
+                                     injections: dict | None = None):
     """Cross-step chained body (paper §4.3, DESIGN.md §6): ``steps``
     optimizer iterations executed back-to-back in ONE ring program of
     ``I*R*S + N - 1`` ticks — step ``T+1``'s round injection begins while
@@ -625,7 +632,9 @@ def roundpipe_async_forward_backward(params, opt_state, batch, worker_id,
     ``g0`` rotates the ring's physical endpoints exactly as in the
     synchronous driver (a supplied ``tick_program``'s stamp governs); the
     staleness-1 protocol is rotation-invariant — versions, parity buffers
-    and D_k ticks are all logical-coordinate.
+    and D_k ticks are all logical-coordinate.  Local slots and
+    ``injections`` are as in the synchronous driver: a local block reads
+    the version its injection step reads.
     """
     n = n_workers
     l_total = cfg.n_layers
@@ -710,7 +719,7 @@ def roundpipe_async_forward_backward(params, opt_state, batch, worker_id,
                      worker_id=worker_id, pool_template=pool,
                      xent_chunk=xent_chunk, kv_chunk=kv_chunk,
                      prefetch_program=prefetch_program, pool_dtype=pool_dtype,
-                     g0=g0)
+                     g0=g0, injections=injections)
     # per-step accumulators are parity-PAIRED (leading dim 2, indexed by the
     # traced work-step, see ring.ParityAccum): on shallow plans (sf < N-1 or
     # S < N) a worker starts step k+1's fused/backward work before step k's
@@ -770,7 +779,7 @@ def roundpipe_async_forward_backward(params, opt_state, batch, worker_id,
             rm.zeros_standby(), slot_idx,
             jax.tree_util.tree_flatten(inj_pool(t_step))[0])
 
-    if prefetch_program is not None:
+    if rm.uses_standby(0):
         standby = _upload_for(0, 0)
 
     for rec in program.records:
@@ -783,7 +792,7 @@ def roundpipe_async_forward_backward(params, opt_state, batch, worker_id,
         if entry is not None:
             t_inj = rec.inject_step        # static injection step
             spec = slots[entry[1]]
-            if prefetch_program is not None:
+            if rm.uses_standby(entry[1]):
                 if spec.size:
                     promoted = (rm.dequant_block(standby[0], standby[1], spec)
                                 if quant
@@ -1058,7 +1067,7 @@ def roundpipe_async_forward_backward(params, opt_state, batch, worker_id,
                         T.lm_head_weights(new_params, cfg))
 
         # ---- standby upload for tick t+1 (after any version publish) --------
-        if prefetch_program is not None and rec.upload is not None:
+        if rec.upload is not None and rm.uses_standby(rec.upload[0]):
             standby = _upload_for(rec.upload[1], rec.upload[0])
 
     if compress:
@@ -1139,7 +1148,9 @@ def _build_mapped(cfg: ModelConfig, mesh, plan, *, xent_chunk: int,
     """The shard_map'ed plan executor over PADDED params.
 
     Returns ``(mapped, l_pad, pspecs, grads_specs)`` where
-    ``mapped(padded_params, batch) -> (padded_grads, loss, tokens)``.
+    ``mapped(padded_params, batch) -> (padded_grads, loss, tokens)``;
+    ``mapped.injections`` holds the last trace's ``local`` and
+    ``streamed`` injection counts (``RingMachine``).
     With ``lora`` the params carry a ``"lora"`` adapter pool and the grads
     pytree holds exactly ``{"lora": ...}`` (frozen-base mode).
     With ``rounds`` the batch leaves must carry a leading round axis
@@ -1170,12 +1181,14 @@ def _build_mapped(cfg: ModelConfig, mesh, plan, *, xent_chunk: int,
     if lora is not None:
         abstract = dict(abstract, lora=lora_mod.adapter_abstract(cfg, lora))
     pspecs = roundpipe_param_specs(cfg, abstract)
+    injections = {}
     body = functools.partial(
         roundpipe_forward_backward, cfg=cfg, plan=plan, n_workers=n,
         l_pad=l_pad, xent_chunk=xent_chunk, kv_chunk=kv_chunk,
         ring_grad_dtype=ring_grad_dtype, prefetch_program=prefetch_program,
         lora=lora, rounds=rounds, pool_dtype=pool_dtype,
-        grad_compress=grad_compress, tick_program=tick_program, g0=g0)
+        grad_compress=grad_compress, tick_program=tick_program, g0=g0,
+        injections=injections)
     if lora is not None:
         grads_specs = {"lora": pspecs["lora"]}
     elif "lm_head" in abstract:
@@ -1208,6 +1221,7 @@ def _build_mapped(cfg: ModelConfig, mesh, plan, *, xent_chunk: int,
             check_vma=False)
         return f(padded_params, batch, jnp.arange(n, dtype=jnp.int32))
 
+    mapped.injections = injections
     return mapped, l_pad, pspecs, grads_specs
 
 
@@ -1342,7 +1356,8 @@ def build_roundpipe_train_step(cfg: ModelConfig, mesh, step_cfg,
     Returns ``(step, state_shardings, batch_shardings, plan)`` — the returned
     plan is the exact object the step executes, so callers can simulate it
     (``simulate_plan(plan, M, round_size=N)``) and compare against the
-    real run.
+    real run.  Once traced, ``step.injections`` holds the program's
+    ``local`` and ``streamed`` ring injections.
     """
     n = axis_size(mesh, AXIS)
     if global_batch % n:
@@ -1459,6 +1474,7 @@ def build_roundpipe_train_step(cfg: ModelConfig, mesh, step_cfg,
                    in_shardings=(state_shardings, batch_shardings),
                    out_shardings=(state_shardings, None),
                    donate_argnums=(0,))
+    step.injections = mapped.injections
     return step, state_shardings, batch_shardings, plan
 
 
@@ -1502,7 +1518,8 @@ def build_roundpipe_async_train_step(cfg: ModelConfig, mesh, step_cfg,
     ``state["opt"]["grad_residual"]`` across the whole chained program —
     the same knobs as the synchronous step.
 
-    Returns ``(multi_step, state_shardings, batch_shardings, plan)``.
+    Returns ``(multi_step, state_shardings, batch_shardings, plan)``;
+    ``multi_step.injections`` as in the synchronous builder.
     """
     from repro.core.consistency import verify_async_ticks
 
@@ -1538,6 +1555,7 @@ def build_roundpipe_async_train_step(cfg: ModelConfig, mesh, step_cfg,
             metrics["step"] = per_step[-1]["step"]
             return state, metrics
 
+        multi_step.injections = sync_step.injections
         stacked_sh = jax.tree.map(
             lambda s: NamedSharding(mesh, P(None, *s.spec)), batch_sh)
         return multi_step, state_sh, stacked_sh, plan
@@ -1580,13 +1598,14 @@ def build_roundpipe_async_train_step(cfg: ModelConfig, mesh, step_cfg,
         ospecs = dict(ospecs, grad_residual=(
             pspecs["lora"] if lora is not None else pspecs["layers"]))
     state_specs = {"params": pspecs, "opt": ospecs}
+    injections = {}
     body = functools.partial(
         roundpipe_async_forward_backward, cfg=cfg, plan=plan, n_workers=n,
         l_pad=l_pad, steps=steps_per_call, rounds=rounds, opt_cfg=step_cfg.opt,
         xent_chunk=step_cfg.xent_chunk, kv_chunk=step_cfg.kv_chunk,
         ring_grad_dtype=step_cfg.accum_dtype, prefetch_program=program,
         lora=lora, pool_dtype=pool_dtype, grad_compress=grad_compress,
-        tick_program=ticks)
+        tick_program=ticks, injections=injections)
 
     batch_abs = {}
     if cfg.frontend:
@@ -1630,6 +1649,7 @@ def build_roundpipe_async_train_step(cfg: ModelConfig, mesh, step_cfg,
                    in_shardings=(state_shardings, batch_shardings),
                    out_shardings=(state_shardings, None),
                    donate_argnums=(0,))
+    step.injections = injections
     return step, state_shardings, batch_shardings, plan
 
 

@@ -20,7 +20,8 @@ parameterization axes:
   (``quantize_pool`` / ``upload_slot_q`` / ``dequant_block`` /
   ``assemble_block_q``) with the fused dequant-on-upload kernel at promote
   time; deposits are exact fp32 (``deposit_plain``) or error-feedback int8
-  (``deposit_ef``).
+  (``deposit_ef``).  On a one-worker ring every slot is a ``local_slot``:
+  ``local_block`` reads it in place from the pool, with no standby.
 * **accumulator family** — :class:`StepAccum` (one buffer per quantity,
   read once at program end — the synchronous shape) or :class:`ParityAccum`
   (2-deep buffers indexed by the traced work-step's parity — the async
@@ -200,7 +201,8 @@ class RingMachine:
     def __init__(self, *, cfg: ModelConfig, plan, n_workers: int, l_pad: int,
                  worker_id, pool_template, xent_chunk: int = 256,
                  kv_chunk: int = 1024, prefetch_program=None,
-                 pool_dtype: str = "none", g0: int = 0):
+                 pool_dtype: str = "none", g0: int = 0,
+                 injections: dict | None = None):
         self.cfg = cfg
         self.plan = plan
         self.n = n_workers
@@ -234,6 +236,13 @@ class RingMachine:
         self.leaf_offs = list(
             itertools.accumulate([0] + self.leaf_elems[:-1]))
         self.row_elems = sum(self.leaf_elems)
+        # trace-time tally of the blocks injected into the rings (the weight
+        # ring and, frozen-base, the adapter ring): ``local`` read in place
+        # from the injection worker's own pool, ``streamed`` through a
+        # standby fill or a whole-block gather.  The step builders pass one
+        # dict and expose it on the built step; each trace restarts it.
+        self.injections = {} if injections is None else injections
+        self.injections.update(local=0, streamed=0)
 
     # ---- ring hop ----------------------------------------------------------
     @spans.scope(spans.SHIFT)
@@ -274,6 +283,55 @@ class RingMachine:
                                           chunk=self.xent_chunk)
         return tot, cnt
 
+    # ---- local-row injection -----------------------------------------------
+    def local_slot(self, spec):
+        """Whether slot ``spec``'s injection block is read in place from the
+        injection worker's own pool shard, with no standby and no gather.
+
+        True on a one-worker ring, where the ownership table puts every row
+        on the injecting worker.  An N>1 slot whose rows all live on the
+        injector is not taken: in the SPMD body every other worker would
+        then need a gated select to add zeros to the ring.
+
+        Assumes the pool lives in device memory.  A pool in host memory
+        (``pinned_host``) makes the upload a real host-to-device transfer,
+        which must go through the standby path (``upload_slot``)."""
+        return self.n == 1 and all(
+            lid // self.per == self.inj for lid in spec.layers)
+
+    def uses_standby(self, slot_idx):
+        """Whether slot ``slot_idx`` is injected through the double-buffered
+        standby (``upload_slot`` then ``promote_standby``/``dequant_block``):
+        under a prefetch program, unless it is a ``local_slot``."""
+        return (self.prefetch_program is not None
+                and not self.local_slot(self.plan.stages[slot_idx]))
+
+    @spans.scope(spans.PROMOTE)
+    def local_block(self, spec, src, quantized: bool = False):
+        """Slot ``spec``'s injection block straight from the local pool rows
+        (``local_slot``): a static slice, with padding rows repeating the
+        slot's first layer exactly as ``promote_standby`` does — on a
+        one-slot ring the slice is the whole pool.  ``src`` is a dense pool
+        pytree, or with ``quantized`` a ``(codes, scales)`` pair, whose rows
+        go through ``dequant_block`` in place."""
+        if not spec.layers:
+            return None
+        self.injections["local"] += 1
+        lo = spec.start % self.per
+
+        def rows(a):
+            blk = a[lo:lo + spec.size]
+            if spec.size < self.kmax:
+                pad = jnp.broadcast_to(
+                    blk[0], (self.kmax - spec.size,) + blk.shape[1:])
+                blk = jnp.concatenate([blk, pad])
+            return blk
+
+        if quantized:
+            codes, scales = src
+            return self.dequant_block(rows(codes), rows(scales), spec)
+        return jax.tree.map(rows, src)
+
     # ---- dense payload codec -----------------------------------------------
     @spans.scope(spans.PROMOTE)
     def assemble_block(self, spec, src_pool):
@@ -283,7 +341,9 @@ class RingMachine:
         ring row holds real weights (finite jacobians for the masked
         lanes).  ``src_pool`` is the parameterization point: the live pool
         (sync), a staleness-1 version entry (async), or the adapter pool
-        (frozen-base LoRA)."""
+        (frozen-base LoRA).  A ``local_slot`` reads its rows in place."""
+        if self.local_slot(spec):
+            return self.local_block(spec, src_pool)
         rows = []
         for lid in spec.layers:
             owner, idx = divmod(lid, self.per)
@@ -291,6 +351,7 @@ class RingMachine:
             rows.append(jax.lax.ppermute(inj, AXIS, [(owner, self.inj)]))
         if not rows:
             return None
+        self.injections["streamed"] += 1
         rows += [rows[0]] * (self.kmax - len(rows))
         return jax.tree.map(lambda *xs: jnp.stack(xs), *rows)
 
@@ -309,6 +370,8 @@ class RingMachine:
         chunk byte-ranges partition each row, so the union of writes equals
         the whole-block gather exactly.  ``pool_leaves`` is the flattened
         source pool (live or versioned)."""
+        if self.plan.stages[slot_idx].layers:
+            self.injections["streamed"] += 1
         stand = list(stand)
         for cu in self.prefetch_program.uploads[slot_idx]:
             if cu.row < 0:          # replicated LM head: never ring-resident
@@ -374,6 +437,8 @@ class RingMachine:
         boundaries still partition every row); the fp32 scale row rides the
         slot's first chunk (its 4B/block are part of the plan's quantized
         byte total)."""
+        if self.plan.stages[slot_idx].layers:
+            self.injections["streamed"] += 1
         q_codes, q_scales = qpair
         code_len = q_codes.shape[1]
         codes, scales = stand
@@ -418,9 +483,13 @@ class RingMachine:
     @spans.scope(spans.PROMOTE)
     def assemble_block_q(self, spec, qpair):
         """Whole-block fallback, quantized: gather full code+scale rows from
-        their owners, then one fused dequant."""
+        their owners, then one fused dequant.  A ``local_slot`` reads its
+        rows in place."""
         if not spec.layers:
             return None
+        if self.local_slot(spec):
+            return self.local_block(spec, qpair, quantized=True)
+        self.injections["streamed"] += 1
         q_codes, q_scales = qpair
         crows, srows = [], []
         for lid in spec.layers:

@@ -176,6 +176,11 @@ def run_cell(arch: str, shape: str, multi_pod: bool, strategy: str,
                 state_abs = abstract_train_state(cfg, step_cfg)
             batch_abs = input_specs(arch, shape)
             lowered = step.lower(state_abs, batch_abs)
+            if strategy == "roundpipe":
+                # ring injections read in place from the local pool against
+                # those streamed through the standby or a gather (counted
+                # while tracing)
+                meta["injections"] = dict(step.injections)
         elif spec.step == "prefill":
             step, psh, bsh, csh = build_prefill_step(
                 cfg, mesh, step_cfg, spec.global_batch, spec.seq_len)

@@ -414,6 +414,12 @@ def run_training(args, *, max_restarts: int = 3) -> dict:
             loop_mgr = mgr
             loop_data = data
             n_calls = args.steps
+        if args.strategy == "roundpipe":
+            # the ring's injection counts exist once the step is traced; the
+            # first call reuses this trace
+            step.trace(like, loop_data.batch(0))
+            print(f"ring injections per call: {step.injections['local']} "
+                  f"local, {step.injections['streamed']} streamed")
 
         def metrics_cb(s, m, dt):
             import numpy as np
