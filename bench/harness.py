@@ -4,12 +4,25 @@ Everything that belongs to a configuration, a traffic mix, a cell or a metric
 is a file found by its name:
 
   bench/configs/<config>.json    the model as run (published keys, the
-                                 registry arch and its fields as run)
+                                 registry arch and its fields as run, and
+                                 ``"reference"``: the name of its module)
+  bench/references/<name>.py     the architecture's float32 reference and
+                                 its operations and bytes from shapes (the
+                                 interface: ``references/qwen3_dense.py``)
   bench/traffic/<mix>.json       batch, sequence, token statistics, LoRA and
                                  optimizer settings of the training job
   bench/workloads/<cell>.json    config + mix + chips + mesh, the control
                                  and the limits of the comparison
   bench/metrics/<metric>.py      ``read(record, trace) -> float | None``
+
+A reader's ``record`` (:func:`run_cell`) holds ``cell``, ``chips``, ``seed``,
+``setup_s``, ``tokens_per_step``, ``window`` (:class:`Window`),
+``peak_bytes``, ``peaks`` (the chip's row of ``peaks.json``),
+``flops_per_token`` and ``dequant_bytes_per_call`` (None without a quantized
+pool), and what a reader needs to count a kernel of its own: ``reference``
+(the configuration's reference module), ``shape`` (its ``Shape`` of the
+configuration), ``job`` (``reference.Job``), ``plan`` (the program's
+``ExecutionPlan``) and ``cfg`` (the program's model config).
 
 ``BENCHMARK.json`` at the checkout's root says which metrics a cell
 reports.  The harness drives the program's synchronous RoundPipe LoRA step
@@ -60,10 +73,17 @@ def load_spec(kind: str, name: str, root: Path = BENCH) -> dict:
 
 
 def load_cell(name: str, root: Path = BENCH) -> dict:
+    """A cell's spec, with its configuration's spec, its mix and its
+    configuration's reference module attached."""
     cell = load_spec("workloads", name, root)
     cell["name"] = name
     cell["config_spec"] = load_spec("configs", cell["config"], root)
     cell["mix"] = load_spec("traffic", cell["traffic"], root)
+    if "reference" not in cell["config_spec"]:
+        raise SpecError(f"bench/configs/{cell['config']}.json names no "
+                        f"reference: give it \"reference\": \"<name>\" "
+                        f"of a module bench/references/<name>.py")
+    cell["reference"] = load_reference(cell["config_spec"]["reference"], root)
     return cell
 
 
@@ -79,18 +99,34 @@ def declared_metrics(cell_name: str, traced: bool,
             if "workloads" not in m or cell_name in m["workloads"]}
 
 
+def _load_module(kind: str, what: str, name: str, root: Path):
+    """``<root>/<kind>/<name>.py`` as a module; an unknown name is an
+    error, never a default."""
+    if not NAME_RE.match(name):
+        raise SpecError(f"bad {what} name {name!r}: no file "
+                        f"bench/{kind}/{name}.py")
+    path = root / kind / f"{name}.py"
+    if not path.is_file():
+        raise SpecError(f"unknown {what} {name!r}: no file "
+                        f"bench/{kind}/{name}.py")
+    mod_name = f"bench_{what}_{name}"
+    spec = importlib.util.spec_from_file_location(mod_name, path)
+    mod = importlib.util.module_from_spec(spec)
+    # registered before it runs: a dataclass looks its module up there
+    sys.modules[mod_name] = mod
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def load_reader(name: str, root: Path = BENCH):
     """``read`` of ``<root>/metrics/<name>.py``."""
-    if not NAME_RE.match(name):
-        raise SpecError(f"bad metric name {name!r}")
-    path = root / "metrics" / f"{name}.py"
-    if not path.is_file():
-        raise SpecError(f"unknown metric {name!r}: no file "
-                        f"bench/metrics/{name}.py")
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load_module("metrics", "metric", name, root).read
+
+
+def load_reference(name: str, root: Path = BENCH):
+    """The reference module ``<root>/references/<name>.py`` (the interface:
+    ``references/qwen3_dense.py``)."""
+    return _load_module("references", "reference", name, root)
 
 
 def peak_entry(device_kind: str, path: Path = BENCH / "peaks.json") -> dict:
@@ -209,10 +245,12 @@ def check_steps(prog: Program, call, state, batches, n: int):
 
 
 def reference_run(cell: dict, seed: int, batches, n: int) -> dict:
-    s = reference.Shape.from_config(cell["config_spec"])
+    """The cell's reference module over its first ``n`` batches."""
+    module = cell["reference"]
+    s = module.Shape.from_config(cell["config_spec"])
     job = reference.Job.from_mix(cell["mix"])
     return reference.run(seed_key(seed),
-                         [batches.batch(i) for i in range(n)], s, job)
+                         [batches.batch(i) for i in range(n)], module, s, job)
 
 
 # ---------------------------------------------------------------------------
@@ -402,7 +440,8 @@ def run_cell(cell: dict, seed: int, seconds: float, traced: bool,
     for line in compare.describe(readings, got, want):
         log(line)
 
-    s = reference.Shape.from_config(cell["config_spec"])
+    module = cell["reference"]
+    s = module.Shape.from_config(cell["config_spec"])
     job = reference.Job.from_mix(mix)
     dev = device_info(chips)
     record = {
@@ -411,11 +450,13 @@ def run_cell(cell: dict, seed: int, seconds: float, traced: bool,
         "tokens_per_step": batches.tokens_per_batch,
         "window": window, "peak_bytes": peak,
         "peaks": peaks,
-        "flops_per_token": flops.step_flops_per_token(s, job, mix["seq"]),
+        "flops_per_token": module.step_flops_per_token(s, job, mix["seq"]),
         "dequant_bytes_per_call": (
-            flops.dequant_bytes(prog.plan.max_block, flops.layer_elems(s),
+            flops.dequant_bytes(prog.plan.max_block, module.layer_elems(s),
                                 prog.pool_dtype)
             if prog.pool_dtype != "none" else None),
+        "reference": module, "shape": s, "job": job, "plan": prog.plan,
+        "cfg": prog.cfg,
     }
     metrics = {}
     for name, unit in metric_units.items():

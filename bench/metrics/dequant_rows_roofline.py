@@ -1,6 +1,7 @@
 """Roofline share of the pool's dequant kernel (``kernels/dequant.py``,
 called by ``ring.dequant_block``), in %: the bytes its calls must move
-(``bench/flops.py``, from shapes), at the chip's HBM bandwidth, over the
+(``bench/flops.py``, from the rows of the plan's largest block and the
+reference module's ``layer_elems``), at the chip's HBM bandwidth, over the
 device time of its events.  The kernel is bound by bytes: it does one
 multiply per element written.
 

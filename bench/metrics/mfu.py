@@ -1,6 +1,7 @@
 """Model FLOP utilization of the whole step, in %: the step's required
-operations per token (``bench/flops.py``: no recomputation, no merge) times
-the traced window's tokens per second, over the chips' bf16 peak."""
+operations per token (the configuration's reference module,
+``step_flops_per_token``: no recomputation, no merge) times the traced
+window's tokens per second, over the chips' bf16 peak."""
 
 
 def read(record, trace):

@@ -8,8 +8,9 @@ with no measured window.
 ``sound`` runs the cell as it is timed; ``control`` runs the program's own
 lower-precision pool named in the cell's file (``control.pool_dtype``),
 compared with the same reference; the other variants are the faults of
-``bench/faults.py``.  Each seed's reference is computed once and every
-variant compared with it.  Prints one JSON line per variant and seed.
+``bench/faults.py``.  Each seed's reference (the module that the cell's
+configuration names, through ``harness.reference_run``) is computed once and
+every variant compared with it.  Prints one JSON line per variant and seed.
 """
 from __future__ import annotations
 

@@ -1,5 +1,6 @@
-"""``bench/flops.py``: hand counts for both configurations, agreement with
-XLA's own count on a tiny model, and the dequant kernel's bytes."""
+"""Operations and bytes from shapes: the ``qwen3_dense`` reference module's
+counts by hand for both configurations and against XLA's own count on a
+tiny model, and ``bench/flops.py``'s dequant kernel bytes."""
 from __future__ import annotations
 
 import functools
@@ -10,10 +11,13 @@ import pytest
 
 from bench import flops, harness, reference as R
 
+D = harness.load_reference("qwen3_dense")
+
 
 def _shape_job(cell):
     c = harness.load_cell(cell)
-    return R.Shape.from_config(c["config_spec"]), R.Job.from_mix(c["mix"]), c
+    assert c["reference"].__file__ == D.__file__
+    return D.Shape.from_config(c["config_spec"]), R.Job.from_mix(c["mix"]), c
 
 
 def _hand(L, d, qd, kvd, ff, V, r, seq):
@@ -35,7 +39,7 @@ def _hand(L, d, qd, kvd, ff, V, r, seq):
 ])
 def test_step_flops_match_a_hand_count(cell, expect):
     s, job, c = _shape_job(cell)
-    got = flops.step_flops_per_token(s, job, c["mix"]["seq"])
+    got = D.step_flops_per_token(s, job, c["mix"]["seq"])
     assert got == pytest.approx(expect, rel=1e-12)
     assert 6.5e9 < got < 7.5e9
 
@@ -43,7 +47,7 @@ def test_step_flops_match_a_hand_count(cell, expect):
 def test_head_share_of_the_32b_cut():
     s, job, c = _shape_job("qwen3-32b-2l.lora-int8")
     head = 4 * s.d_model * s.vocab
-    share = head / flops.step_flops_per_token(s, job, c["mix"]["seq"])
+    share = head / D.step_flops_per_token(s, job, c["mix"]["seq"])
     assert 0.40 < share < 0.50
 
 
@@ -52,10 +56,10 @@ def _unrolled_loss(ad, base, batch, s, job):
     matmul appears once in the HLO, so XLA's count is the whole step's."""
     x = jnp.take(base["embed"], batch["tokens"], axis=0).astype(jnp.float32)
     for i in range(s.n_layers):
-        x = R._layer(x, jax.tree.map(lambda a: a[i], base["layers"]),
+        x = D._layer(x, jax.tree.map(lambda a: a[i], base["layers"]),
                      jax.tree.map(lambda a: a[i], ad), s,
                      job.alpha / job.rank)
-    x = R._rms(x, base["final_norm"]["scale"], s.eps)
+    x = D._rms(x, base["final_norm"]["scale"], s.eps)
     logits = x @ base["lm_head"].astype(jnp.float32)
     lse = jax.scipy.special.logsumexp(logits, -1)
     gold = jnp.take_along_axis(logits, batch["labels"][..., None], -1)[..., 0]
@@ -65,11 +69,11 @@ def _unrolled_loss(ad, base, batch, s, job):
 def test_step_flops_match_xla_cost_analysis_on_a_tiny_model():
     """XLA counts full (non-causal) attention products, recomputes nothing
     here and skips the first layer's input gradient: within 2%."""
-    s = R.Shape(n_layers=2, d_model=256, n_heads=4, n_kv_heads=2, d_head=64,
+    s = D.Shape(n_layers=2, d_model=256, n_heads=4, n_kv_heads=2, d_head=64,
                 d_ff=768, vocab=1024, tied=False, rope_theta=1e6, eps=1e-6)
     _, job, _ = _shape_job("qwen3-1.7b.lora-bf16")
     seq = 128
-    base, ad = jax.eval_shape(functools.partial(R.make_weights, s=s, job=job),
+    base, ad = jax.eval_shape(functools.partial(D.make_weights, s=s, job=job),
                               jax.random.PRNGKey(0))
     ad = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, jnp.float32), ad)
     batch = {k: jax.ShapeDtypeStruct((1, seq), jnp.int32)
@@ -77,13 +81,13 @@ def test_step_flops_match_xla_cost_analysis_on_a_tiny_model():
     grad = jax.jit(jax.grad(functools.partial(_unrolled_loss, s=s, job=job)))
     cost = grad.lower(ad, base, batch).compile().cost_analysis()
     cost = cost[0] if isinstance(cost, list) else cost
-    want = flops.step_flops_per_token(s, job, seq, causal=False) * seq
+    want = D.step_flops_per_token(s, job, seq, causal=False) * seq
     assert cost["flops"] == pytest.approx(want, rel=0.02)
 
 
 def test_layer_elems_and_dequant_bytes_come_from_shapes():
     s, _, _ = _shape_job("qwen3-32b-2l.lora-int8")
-    elems = flops.layer_elems(s)
+    elems = D.layer_elems(s)
     assert elems == 5120 * (8192 + 2 * 1024) + 8192 * 5120 \
         + 3 * 5120 * 25600 + 2 * 5120
     blocks = -(-elems // 256)

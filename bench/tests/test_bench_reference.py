@@ -1,6 +1,8 @@
-"""``bench/reference.py`` stands apart from the program and agrees with it:
-the same weights from the seed, bit for bit; the same float32 loss and
-adapter gradients as the model code run in float32; the pool's codec."""
+"""``bench/reference.py`` and the configurations' reference module
+``bench/references/qwen3_dense.py`` stand apart from the program and agree
+with it: the same weights from the seed, bit for bit; the same float32 loss
+and adapter gradients as the model code run in float32; the pool's
+codec."""
 from __future__ import annotations
 
 import functools
@@ -20,6 +22,13 @@ def cell(tmp_path_factory):
     return harness.load_cell("tiny", root)
 
 
+@pytest.fixture(scope="module")
+def D(cell):
+    """The tiny configuration's reference module: ``qwen3_dense``."""
+    assert cell["config_spec"]["reference"] == "qwen3_dense"
+    return cell["reference"]
+
+
 def _program_state(cell, key):
     from repro.core.dispatch import init_roundpipe_state
     from repro.launch.steps import StepConfig
@@ -33,15 +42,16 @@ def _program_state(cell, key):
 
 
 def test_reference_imports_nothing_of_the_program():
-    text = open(R.__file__).read()
-    assert "repro" not in text
+    for path in [R.__file__, *(harness.BENCH / "references").glob("*.py")]:
+        text = open(path).read()
+        assert "repro" not in text, path
 
 
-def test_same_weights_from_the_seed(cell):
+def test_same_weights_from_the_seed(cell, D):
     key = harness.seed_key(2 ** 33 + 7)
-    s = R.Shape.from_config(cell["config_spec"])
+    s = D.Shape.from_config(cell["config_spec"])
     job = R.Job.from_mix(cell["mix"])
-    base, adapters = R.make_weights(key, s, job)
+    base, adapters = D.make_weights(key, s, job)
     params = _program_state(cell, key)["params"]
     want = {k: v for k, v in params.items() if k != "lora"}
     assert jax.tree.structure(base) == jax.tree.structure(want)
@@ -52,16 +62,16 @@ def test_same_weights_from_the_seed(cell):
         assert a.dtype == b.dtype and np.array_equal(a, b)
 
 
-def test_float32_loss_and_grads_match_the_model_code(cell):
+def test_float32_loss_and_grads_match_the_model_code(cell, D):
     """With every weight in float32 and the adapters' B non-zero, the
     reference's unmerged LoRA layer equals the model code's merged one."""
     from repro.models import lora as lora_mod
     from repro.models import transformer as T
     from repro.models.lora import LoraConfig
-    s = R.Shape.from_config(cell["config_spec"])
+    s = D.Shape.from_config(cell["config_spec"])
     job = R.Job.from_mix(cell["mix"])
     cfg = harness.model_config(cell["config_spec"])
-    base, adapters = R.make_weights(jax.random.PRNGKey(3), s, job)
+    base, adapters = D.make_weights(jax.random.PRNGKey(3), s, job)
     f32 = functools.partial(jax.tree.map, lambda a: a.astype(jnp.float32))
     base = f32(base)
     keys = iter(jax.random.split(jax.random.PRNGKey(4), 64))
@@ -79,7 +89,7 @@ def test_float32_loss_and_grads_match_the_model_code(cell):
 
     with jax.default_matmul_precision("highest"):
         want, gw = jax.value_and_grad(program_loss)(adapters)
-        got, gg = jax.value_and_grad(R.loss_fn)(adapters, base, batch, s,
+        got, gg = jax.value_and_grad(D.loss_fn)(adapters, base, batch, s,
                                                  job)
     assert float(got) == pytest.approx(float(want), rel=1e-5)
     for a, b in zip(jax.tree.leaves(gg), jax.tree.leaves(gw)):
@@ -88,13 +98,13 @@ def test_float32_loss_and_grads_match_the_model_code(cell):
 
 
 @pytest.mark.parametrize("pool", ["int8", "int4"])
-def test_codec_matches_the_program_quantizer(cell, pool):
+def test_codec_matches_the_program_quantizer(cell, D, pool):
     from repro.kernels.dequant import quantize_rows
     from repro.kernels.ref import dequant_rows_ref
-    s = R.Shape.from_config(cell["config_spec"])
+    s = D.Shape.from_config(cell["config_spec"])
     job = R.Job.from_mix(cell["mix"])
-    base, _ = R.make_weights(jax.random.PRNGKey(5), s, job)
-    got = R._codec(base["layers"], pool)
+    base, _ = D.make_weights(jax.random.PRNGKey(5), s, job)
+    got = R.codec(base["layers"], pool)
     leaves = jax.tree.leaves(base["layers"])
     rows = jnp.concatenate([l.reshape(s.n_layers, -1) for l in leaves], 1)
     # jitted, as inside the step: eager division rounds some ties apart

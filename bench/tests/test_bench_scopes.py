@@ -101,12 +101,18 @@ def test_every_scope_reaches_the_step(tmp_path, monkeypatch, ring4, pool,
     """The tiny cell's step on one device: compiled with an int8 pool over a
     plan with every slot kind, where every scope reaches the compiled HLO;
     lowered with a bf16 pool over its auto plan (one fused slot, as on the
-    chip; the lowered driver still holds every slot kind's branch), for
-    the bf16 pool's upload and standby zeros.  And its N=4 ring
-    on four virtual devices, lowered, where every collective-permute
-    carries a ring primitive's scope and the ring hop's carry
-    ``rp.shift``."""
+    chip; the lowered driver still holds every slot kind's branch).  A
+    one-worker ring reads each block in place from its pool, so no
+    standby is uploaded there and ``rp.upload`` is not among its scopes;
+    a bf16 pool's block in place is the pool itself, an identity slice
+    with no op, so ``rp.promote`` is not among the bf16 step's either (the
+    int8 pool's dequant in place carries it).  And its N=4 ring on four
+    virtual devices, lowered, where the standby upload runs, every
+    collective-permute carries a ring primitive's scope and the ring hop's
+    carry ``rp.shift``."""
     want = set(spans.DEVICE_SCOPES)
+    if devices == 1:
+        want.discard(spans.UPLOAD)
     if devices == 4:
         got = ring4()
         assert spans.SHIFT in got["permutes"]
@@ -119,7 +125,7 @@ def test_every_scope_reaches_the_step(tmp_path, monkeypatch, ring4, pool,
         got = {"scopes": set(found.values()) - {""}}
     else:
         got = _lowered_scopes(_write_cell(tmp_path, pool_dtype=pool))
-        want.discard(spans.QUANTIZE)
+        want -= {spans.QUANTIZE, spans.PROMOTE}
     assert set(got["scopes"]) == want
 
 

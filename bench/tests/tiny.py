@@ -15,6 +15,7 @@ control and the faults; ``python3 bench/readings.py --specs``):
 from __future__ import annotations
 
 import json
+import shutil
 from pathlib import Path
 
 from bench import harness
@@ -41,20 +42,26 @@ PAIRS = {"num_hidden_layers": "n_layers", "hidden_size": "d_model",
 
 
 def write_cell(root: Path, *, like: str = "qwen3-1.7b.lora-bf16",
-               small: bool = False, name: str = "tiny") -> Path:
+               small: bool = False, name: str = "tiny",
+               reference: str = "qwen3_dense") -> Path:
     """``configs/``, ``traffic/`` and ``workloads/`` for a cell ``name``
     that keeps ``like``'s job, control and faults; the small cell has its
-    own limits and sequence."""
+    own limits and sequence.  The configuration names ``reference``; the
+    benchmark's module of that name, where there is one, is copied into
+    ``references/``."""
     real = harness.load_cell(like)
     published, model, seq = dict(PUBLISHED), dict(MODEL), 64
     if small:
         published.update(SMALL)
         model.update({PAIRS[k]: v for k, v in SMALL.items()})
         seq = 128
-    for kind in ("configs", "traffic", "workloads"):
+    for kind in ("configs", "traffic", "workloads", "references"):
         (root / kind).mkdir(parents=True, exist_ok=True)
-    config = dict(published, source="test", arch="qwen3-1.7b", reduced=[],
-                  model=model)
+    module = harness.BENCH / "references" / f"{reference}.py"
+    if module.is_file():
+        shutil.copy(module, root / "references")
+    config = dict(published, source="test", arch="qwen3-1.7b",
+                  reference=reference, reduced=[], model=model)
     (root / "configs" / f"{name}.json").write_text(json.dumps(config))
     (root / "traffic" / f"{name}-mix.json").write_text(
         json.dumps(dict(real["mix"], seq=seq)))
